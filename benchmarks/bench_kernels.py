@@ -60,8 +60,8 @@ def paged_attention_sweep():
     n_blocks = B * nb_lane  # pool exactly covers the lanes' tables
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.normal(size=(B, KV, G, d)), jnp.float32)
-    k_pool = jnp.asarray(rng.normal(size=(n_blocks, bs, KV, d)), jnp.float32)
-    v_pool = jnp.asarray(rng.normal(size=(n_blocks, bs, KV, d)), jnp.float32)
+    k_pool = jnp.asarray(rng.normal(size=(n_blocks, KV, bs, d)), jnp.float32)
+    v_pool = jnp.asarray(rng.normal(size=(n_blocks, KV, bs, d)), jnp.float32)
     table = jnp.asarray(rng.permutation(n_blocks).reshape(B, nb_lane), jnp.int32)
     row_bytes = KV * d * 4 * 2  # one K row + one V row, f32
     gather_bytes = B * nb_lane * bs * row_bytes  # flat: full pool view per lane
@@ -73,7 +73,7 @@ def paged_attention_sweep():
         live_blocks = int(np.sum(np.asarray(pos) // bs + 1))
         kernel_bytes = live_blocks * bs * row_bytes
         us_k, _ = time_call(lambda: ops.paged_attention(
-            q, k_pool, v_pool, table, pos, use_pallas=True, interpret=True))
+            q, k_pool, v_pool, table, pos, use_pallas=True))
         us_g, _ = time_call(lambda: ops.paged_attention(
             q, k_pool, v_pool, table, pos, use_pallas=False))
         ratio = gather_bytes / kernel_bytes

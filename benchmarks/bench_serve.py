@@ -612,7 +612,12 @@ def main(argv=None):
     if bool(args.data_parallel) != bool(args.model_parallel):
         raise SystemExit("--data-parallel and --model-parallel must be given together")
     n_dev = args.data_parallel * args.model_parallel
-    if n_dev > 1:  # must happen before jax initialises
+    # Fake host devices stand in for a mesh only where no TPU is
+    # attached (counted from PCI ids, without initialising jax); the
+    # flag must be set before jax initialises.
+    from jax._src.hardware_utils import num_available_tpu_chips_and_device_id
+
+    if n_dev > 1 and num_available_tpu_chips_and_device_id()[0] == 0:
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={n_dev}"
@@ -622,6 +627,7 @@ def main(argv=None):
 
     from benchmarks.common import emit
     from repro.configs import reduced_config
+    from repro.launch.mesh import make_mesh
     from repro.models import init_params
 
     cfg = reduced_config(args.arch)
@@ -632,8 +638,7 @@ def main(argv=None):
         params = pack_model_params(params, args.packed_bits)
     mesh = None
     if n_dev > 1:
-        mesh = jax.make_mesh((args.data_parallel, args.model_parallel),
-                             ("data", "model"))
+        mesh = make_mesh((args.data_parallel, args.model_parallel), ("data", "model"))
     reqs, arrivals = build_workload(cfg, args.requests, args.max_new, args.arrival_rate)
 
     b_results, b_wall, b_toks, b_programs = run_bucketed(params, cfg, reqs, args.max_len)

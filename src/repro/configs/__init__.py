@@ -40,6 +40,24 @@ def get_config(arch: str) -> ModelConfig:
     return REGISTRY[arch]
 
 
+def tp_share(cfg: ModelConfig, n: int) -> ModelConfig:
+    """What one chip holds of every layer when each layer is divided
+    ``n`` ways (Megatron tensor parallelism): ``n_heads / n`` query
+    heads, ``n_kv_heads / n`` KV heads, ``d_ff / n`` FFN columns and
+    ``vocab / n`` vocabulary rows.  The widths d_model and head_dim stay
+    as published; the absent shares' contributions are left out."""
+    if n == 1:
+        return cfg
+    for name in ("n_heads", "n_kv_heads", "d_ff"):
+        if getattr(cfg, name) % n:
+            raise ValueError(f"{cfg.name}: {name}={getattr(cfg, name)} does not "
+                             f"divide over {n} chips")
+    return dataclasses.replace(
+        cfg, name=f"{cfg.name}-tp{n}share", n_heads=cfg.n_heads // n,
+        n_kv_heads=cfg.n_kv_heads // n, head_dim=cfg.resolved_head_dim,
+        d_ff=cfg.d_ff // n, vocab_size=-(-cfg.vocab_size // n))
+
+
 def reduced_config(arch: str) -> ModelConfig:
     """Tiny same-family variant for CPU smoke tests: few layers, small
     width/experts/vocab — the architecture *shape* (pattern, GQA ratio,

@@ -82,28 +82,15 @@ def tree_compressed_psum_ef(
 # ---------------------------------------------------------------------------
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions (kw rename / move from
-    jax.experimental); replication checking off — the compressed psum
-    returns replicated outputs by construction."""
-    import inspect
-
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-
-    params = inspect.signature(sm).parameters
-    no_check = {"check_vma": False} if "check_vma" in params else {"check_rep": False}
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **no_check)
-
-
 def dp_shard_map(per_shard, mesh, axis: str):
     """Wrap the compressed-DP per-shard step: params replicated in,
     (residual, batch) sharded over ``axis``; (loss, metrics, grads)
-    replicated out, residual sharded back."""
-    return shard_map_compat(
+    replicated out, residual sharded back.  Replication checking is off:
+    the compressed psum returns replicated outputs by construction."""
+    return jax.shard_map(
         per_shard,
-        mesh,
+        mesh=mesh,
         in_specs=(P(), P(axis), P(axis)),
         out_specs=(P(), P(), P(), P(axis)),
+        check_vma=False,
     )

@@ -21,7 +21,7 @@ embedding ``embed`` (V, d)                  ``P("model", "data")``
 stacked MoE experts (L, E, in, out)         experts -> ``"model"``
 norm scales / biases / BSQ scales / masks   replicated
 KV cache (B, S, KV, hd)                     ``P("data", None, "model", None)``
-paged KV block pool (Nb, bs, KV, hd)        block axis -> ``"data"`` (as slots)
+paged KV block pool (Nb, KV, bs, hd)        block axis -> ``"data"`` (as slots)
 block table (n_slots, blocks_per_lane)      lanes -> data axes when they
                                             co-shard with pool blocks,
                                             else replicated
@@ -348,7 +348,7 @@ def slot_pool_specs(pool_state: PyTree, mesh) -> PyTree:
 
 
 def paged_block_spec(shape: Tuple[int, ...], mesh) -> P:
-    """Spec for one paged KV pool leaf ``(n_blocks, block_size, KV, hd)``.
+    """Spec for one paged KV pool leaf ``(n_blocks, KV, block_size, hd)``.
 
     The block axis takes the slot axis's role and spreads over the data
     axes; KV heads go to model when divisible.  The intra-block row axis
@@ -358,11 +358,11 @@ def paged_block_spec(shape: Tuple[int, ...], mesh) -> P:
     accesses into a cross-device reshuffle (XLA falls back to full
     rematerialisation of the pool per step).
     """
-    Nb, _bs, KV, _hd = shape
+    Nb, KV, _bs, _hd = shape
     spec: list = [None] * 4
     spec[0] = dp_axes(mesh, Nb)
     if KV > 1 and _fits(mesh, "model", KV):
-        spec[2] = "model"
+        spec[1] = "model"
     return P(*spec)
 
 
@@ -439,7 +439,7 @@ def block_pool_specs(pool_state: PyTree, mesh, n_blocks: int, block_size: int) -
             stacked = segs and segs[0] == "blocks"
             shape = tuple(leaf.shape)[1:] if stacked else tuple(leaf.shape)
             if (segs[-1].lower() in ("k", "v") and len(shape) == 4
-                    and shape[:2] == (n_blocks, block_size)):
+                    and (shape[0], shape[2]) == (n_blocks, block_size)):
                 s = paged_block_spec(shape, mesh)
             else:
                 s = cache_spec(segs[-1], shape, mesh)

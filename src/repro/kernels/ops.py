@@ -19,13 +19,19 @@ from .flash_attention import flash_attention_pallas
 from .paged_attention import paged_attention_pallas
 
 
+class ShardFallbackWarning(UserWarning):
+    """``bitserial_matmul_sharded`` fell back to the unsharded call, so
+    the packed bytes are gathered at the kernel.  A category of its own
+    so a path that must stay sharded can turn it into an error."""
+
+
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
 def bitserial_matmul(
     x: jax.Array, pw: PackedWeight, *, active_planes=None,
-    use_pallas: bool | None = None, interpret: bool | None = None
+    use_pallas: bool | None = None, interpret: bool | None = None, out_dtype=None,
 ) -> jax.Array:
     """x (..., K) @ packed weight (K, N) with on-the-fly dequantisation.
 
@@ -40,6 +46,9 @@ def bitserial_matmul(
     ``core.packing.truncate_packed(pw, a)`` while ONE compiled program
     serves every precision level (the spec-decode draft dispatch).
     ``None`` keeps the fully static path untouched.
+
+    ``out_dtype`` (default ``x.dtype``) is the dtype the float32
+    accumulator is rounded to.
     """
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
@@ -48,27 +57,33 @@ def bitserial_matmul(
         interpret = (not _on_tpu()) if interpret is None else interpret
         M, K = x2.shape
         N = pw.sign.shape[-1]
-        bm = 128 if M % 128 == 0 else (8 if M % 8 == 0 else M)
+        # Rows pad to a multiple of the (8-aligned) row tile, so every
+        # M — decode's n_slots, a prefill chunk's n_slots * C — gets a
+        # legal, bounded TPU block.
+        bm = min(128, -(-M // 8) * 8)
+        x2 = jnp.pad(x2, ((0, -(-M // bm) * bm - M), (0, 0)))
         bn = 128 if N % 128 == 0 else N
         bk = 512 if K % 512 == 0 else (128 if K % 128 == 0 else K)
         if active_planes is None:
             out = bitserial_matmul_pallas(
                 x2, pw.planes, pw.sign, scale_row(pw.scale, N), n_bits=pw.n_bits,
-                denom_bits=pw.denom_bits,
-                block_m=bm, block_n=bn, block_k=bk, interpret=interpret,
+                denom_bits=pw.denom_bits, block_m=bm, block_n=bn, block_k=bk,
+                out_dtype=out_dtype, interpret=interpret,
             )
         else:
             out = bitserial_matmul_pallas_dyn(
                 x2, pw.planes, pw.sign, scale_row(pw.scale, N),
                 jnp.asarray(active_planes, jnp.int32).reshape(1, 1),
                 n_bits=pw.n_bits, denom_bits=pw.denom_bits,
-                block_m=bm, block_n=bn, block_k=bk, interpret=interpret,
+                block_m=bm, block_n=bn, block_k=bk, out_dtype=out_dtype,
+                interpret=interpret,
             )
+        out = out[:M]
     else:
         out = ref.bitserial_matmul_ref(
             x2, pw.planes, pw.sign, pw.scale, pw.n_bits,
             denom_bits=pw.denom_bits, active_planes=active_planes,
-        )
+        ).astype(out_dtype or x.dtype)
     return out.reshape(*lead, -1)
 
 
@@ -136,12 +151,10 @@ def bitserial_matmul_sharded(
             f"scale shape {tuple(s.shape)}, k={pw.k}) — local shard blocks "
             "are ill-defined (indivisible K8/N/scale groups or padded K); "
             "packed bytes will be gathered at the kernel call",
-            stacklevel=2,
+            ShardFallbackWarning, stacklevel=2,
         )
         return bitserial_matmul(x, pw, active_planes=active_planes,
                                 use_pallas=use_pallas, interpret=interpret)
-
-    from ..dist.collectives import shard_map_compat
 
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
@@ -155,35 +168,39 @@ def bitserial_matmul_sharded(
         pw, planes=P(None, k_ax, n_ax), sign=P(k_ax, n_ax), scale=s_spec
     )
 
+    # Partial products stay float32 through the psum and round to
+    # x.dtype once, as the unsharded kernel rounds its float32
+    # accumulator once: sharded and single-device outputs then agree to
+    # the last bit but for rare rounding ties.
     if active_planes is None:
         def local(xl, pwl):
-            y = bitserial_matmul(xl, pwl, use_pallas=use_pallas, interpret=interpret)
+            y = bitserial_matmul(xl, pwl, use_pallas=use_pallas, interpret=interpret,
+                                 out_dtype=jnp.float32)
             if k_ax is not None:
                 y = jax.lax.psum(y, k_ax)
             return y
 
-        f = shard_map_compat(
-            local, mesh, in_specs=(P(None, k_ax), spec_pw), out_specs=P(None, n_ax)
-        )
-        return f(x2, pw).reshape(*lead, -1)
+        f = jax.shard_map(local, mesh=mesh, in_specs=(P(None, k_ax), spec_pw),
+                          out_specs=P(None, n_ax), check_vma=False)
+        return f(x2, pw).astype(x.dtype).reshape(*lead, -1)
 
     # Runtime active-plane count: a replicated (1, 1) scalar operand —
     # every shard masks the same planes of its LOCAL packed bytes, so
     # the packed sharding (and the psum stitching) is unchanged.
     def local_dyn(xl, pwl, al):
-        y = bitserial_matmul(xl, pwl, active_planes=al,
-                             use_pallas=use_pallas, interpret=interpret)
+        y = bitserial_matmul(xl, pwl, active_planes=al, use_pallas=use_pallas,
+                             interpret=interpret, out_dtype=jnp.float32)
         if k_ax is not None:
             y = jax.lax.psum(y, k_ax)
         return y
 
-    f = shard_map_compat(
-        local_dyn, mesh,
+    f = jax.shard_map(
+        local_dyn, mesh=mesh,
         in_specs=(P(None, k_ax), spec_pw, P(None, None)),
-        out_specs=P(None, n_ax),
+        out_specs=P(None, n_ax), check_vma=False,
     )
     a2 = jnp.asarray(active_planes, jnp.int32).reshape(1, 1)
-    return f(x2, pw, a2).reshape(*lead, -1)
+    return f(x2, pw, a2).astype(x.dtype).reshape(*lead, -1)
 
 
 def bgl_sumsq(x: jax.Array, *, use_pallas: bool | None = None, interpret: bool | None = None):

@@ -13,7 +13,9 @@ Layout contract (mirrors ``SlotPool`` / ``init_cache``):
 
 * ``q``          (B, KV, G, d)  single decode query per lane, kv-major
   GQA head layout (head h = kv * G + g, matching ``_gqa_scores``).
-* ``k_pool/v_pool`` (n_blocks, block_size, KV, d)  the global pools.
+* ``k_pool/v_pool`` (n_blocks, KV, block_size, d)  the global pools,
+  head-major so one grid step's K/V block is a whole (block_size, d)
+  tile of one KV head (a legal TPU block: both dims are full).
 * ``block_table`` (B, blocks_per_lane) int32  pool block id of each
   lane-logical block; stale/unallocated entries may hold anything.
 * ``pos``        (B,) int32  last written row per lane; ``pos < 0``
@@ -98,7 +100,7 @@ def paged_attention_pallas(q, k_pool, v_pool, block_table, pos, *,
                            window=None, sm_scale=None, interpret=False):
     """Paged decode attention; see module docstring for the layout."""
     B, KV, G, d = q.shape
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[2]
     nb_lane = block_table.shape[1]
     if sm_scale is None:
         sm_scale = d**-0.5
@@ -114,15 +116,15 @@ def paged_attention_pallas(q, k_pool, v_pool, block_table, pos, *,
         # Frozen outside [lo, hi]: the index repeats, so Pallas issues
         # no DMA for the blocks @pl.when skips.
         jm = jnp.clip(j, lo, hi)
-        return (tbl[b, jm], 0, h, 0)
+        return (tbl[b, jm], h, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, KV, nb_lane),
         in_specs=[
             pl.BlockSpec((None, None, G, d), lambda b, h, j, tbl, pos_: (b, h, 0, 0)),
-            pl.BlockSpec((None, bs, None, d), kv_map),
-            pl.BlockSpec((None, bs, None, d), kv_map),
+            pl.BlockSpec((None, None, bs, d), kv_map),
+            pl.BlockSpec((None, None, bs, d), kv_map),
         ],
         out_specs=pl.BlockSpec((None, None, G, d), lambda b, h, j, tbl, pos_: (b, h, 0, 0)),
         scratch_shapes=[

@@ -84,8 +84,8 @@ def paged_attention_ref(q, k_pool, v_pool, block_table, pos, *, window=None,
     """Naive f32 softmax decode attention over the block-table gather.
 
     ``q``: (B, KV, G, d) single-query heads (kv-major GQA layout);
-    ``k_pool``/``v_pool``: the global paged pools (n_blocks, block_size,
-    KV, d); ``block_table``: (B, blocks_per_lane) int32; ``pos``: (B,)
+    ``k_pool``/``v_pool``: the global paged pools (n_blocks, KV,
+    block_size, d); ``block_table``: (B, blocks_per_lane) int32; ``pos``: (B,)
     int32 per-lane positions.  Lane b attends its lane-logical rows
     ``[0, pos[b]]`` (optionally windowed) gathered out of the pool —
     stale/unallocated table entries are masked by the causal bound
@@ -94,12 +94,12 @@ def paged_attention_ref(q, k_pool, v_pool, block_table, pos, *, window=None,
     Pallas kernel's empty accumulator meets for free).
     """
     B, KV, G, d = q.shape
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[2]
     L = block_table.shape[1] * bs
     if sm_scale is None:
         sm_scale = d**-0.5
-    keys = k_pool[block_table].reshape(B, L, KV, d)
-    vals = v_pool[block_table].reshape(B, L, KV, d)
+    keys = k_pool[block_table].transpose(0, 1, 3, 2, 4).reshape(B, L, KV, d)
+    vals = v_pool[block_table].transpose(0, 1, 3, 2, 4).reshape(B, L, KV, d)
     s = jnp.einsum("bkgd,bskd->bkgs", q.astype(jnp.float32),
                    keys.astype(jnp.float32)) * sm_scale
     kpos = jnp.arange(L)

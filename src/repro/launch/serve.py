@@ -1,9 +1,15 @@
 """Serving launcher: load (or train-and-quantise) a model, serve requests.
 
     PYTHONPATH=src python -m repro.launch.serve --arch granite-3-2b \
-        --requests 8 --max-new 32 [--scheme /path/scheme.json] \
+        --requests 8 --max-new 32 [--full] [--packed-bits 6] \
         [--data-parallel N --model-parallel M] \
         [--continuous --slots 8 --arrival-rate 0.5 --mixed-lens]
+
+The model is the reduced smoke-size variant of ``--arch`` unless
+``--full`` asks for the published widths; weights are random from a
+fixed seed.  With ``--packed-bits`` they are drawn and packed one
+superblock at a time (``models.init_packed_params``), so a published
+width never holds its float model on the device.
 
 Scheduling modes:
 
@@ -78,10 +84,13 @@ the required metric families and the trace schema, and prints
 ``OBS_SMOKE_OK`` (the CI wiring).
 """
 import argparse
+import resource
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from .cache import setup_compilation_cache
 
 
 def poisson_arrivals(n: int, rate: float, seed: int = 0):
@@ -95,9 +104,15 @@ def poisson_arrivals(n: int, rate: float, seed: int = 0):
     return np.floor(np.cumsum(gaps)).astype(int).tolist()
 
 
-def main():
+def main(argv=None):
+    """Parse ``argv`` (default: the command line), serve, and return
+    ``(engine, results)`` so a caller in the same process can inspect
+    the compiled programs and outputs."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-2b")
+    ap.add_argument("--full", action="store_true",
+                    help="serve the published widths (default: the reduced "
+                         "smoke-size variant of --arch)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--max-new", type=int, default=32)
@@ -210,7 +225,7 @@ def main():
                     help="after serving, scrape the metrics endpoint once, "
                          "validate the exposition + required families + trace "
                          "schema, and print OBS_SMOKE_OK (CI)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.chunked_prefill and not args.continuous:
         raise SystemExit("--chunked-prefill requires --continuous")
     if args.paged and not args.continuous:
@@ -250,19 +265,21 @@ def main():
                              f"--draft-planes {args.draft_planes} (the "
                              "verify must add information over the draft)")
 
-    from ..configs import reduced_config
+    from ..configs import get_config, reduced_config
     from ..data import MarkovLM
     from ..dist import elastic
-    from ..models import init_params
+    from ..models import init_packed_params, init_params
     from ..serve import Request, ServeEngine
+    from .mesh import make_mesh
 
-    cfg = reduced_config(args.arch)
+    setup_compilation_cache()
+    cfg = get_config(args.arch) if args.full else reduced_config(args.arch)
     mesh = None
     if bool(args.data_parallel) != bool(args.model_parallel):
         raise SystemExit("--data-parallel and --model-parallel must be given together "
                          "(use 1 for an unsharded axis)")
     if args.data_parallel and args.model_parallel:
-        mesh = jax.make_mesh((args.data_parallel, args.model_parallel), ("data", "model"))
+        mesh = make_mesh((args.data_parallel, args.model_parallel), ("data", "model"))
         # Advisory only: the engine tolerates indivisible buckets (batch
         # axis replicated), it just loses the data-parallel speedup.
         if not elastic.validate_batch_divisibility(args.requests, mesh):
@@ -271,14 +288,22 @@ def main():
                 f"the data axis ({dict(mesh.shape)}); buckets will run with a "
                 "replicated batch axis"
             )
-    params = init_params(jax.random.PRNGKey(0), cfg)
     if args.packed_bits:
-        from ..core.packing import pack_model_params, packed_leaves
+        from ..core.packing import packed_leaves
 
-        params = pack_model_params(params, args.packed_bits)
+        # Drawn and packed superblock by superblock: the float model of a
+        # published width never has to fit on the device.
+        params = init_packed_params(jax.random.PRNGKey(0), cfg, args.packed_bits)
         packed_bytes = sum(pw.hbm_bytes() for pw in packed_leaves(params))
         print(f"[serve] packed weights at {args.packed_bits}b: "
               f"{packed_bytes / 1e6:.2f} MB global")
+    else:
+        params = init_params(jax.random.PRNGKey(0), cfg)
+    stats = jax.devices()[0].memory_stats()
+    if stats and "peak_bytes_in_use" in stats:
+        print(f"[serve] device peak bytes in use after init: "
+              f"{stats['peak_bytes_in_use']}; host peak RSS "
+              f"{resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024}")
     from ..obs import Observability, get_registry
 
     # Wire the engine to the PROCESS-GLOBAL registry (engines default to a
@@ -396,6 +421,7 @@ def main():
         _obs_smoke(args, obs, server, engine)
     if server is not None:
         server.close()
+    return engine, results
 
 
 def _obs_smoke(args, obs, server, engine):

@@ -4,6 +4,7 @@ from .transformer import (  # noqa: F401
     decode_step,
     forward,
     init_cache,
+    init_packed_params,
     init_params,
     loss_fn,
     param_count,

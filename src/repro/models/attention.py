@@ -113,15 +113,19 @@ def attention(
 
 
 def _pool_gather(cache_k, cache_v, block_table, n_kv: int, head_dim: int):
-    """Lane-logical (B, nb_lane*bs, K, d) views of both pools.
+    """Lane-logical (B, nb_lane*bs, K, d) views of both (n_blocks, K, bs,
+    d) pools.
 
     The flattened table index is built once and shared by the K and V
     gathers (they are two reads, one index computation)."""
-    B = block_table.shape[0]
+    B, nb_lane = block_table.shape
     idx = block_table.reshape(-1)
-    keys = jnp.take(cache_k, idx, axis=0).reshape(B, -1, n_kv, head_dim)
-    vals = jnp.take(cache_v, idx, axis=0).reshape(B, -1, n_kv, head_dim)
-    return keys, vals
+
+    def view(pool):
+        blocks = jnp.take(pool, idx, axis=0).reshape(B, nb_lane, n_kv, -1, head_dim)
+        return blocks.transpose(0, 1, 3, 2, 4).reshape(B, -1, n_kv, head_dim)
+
+    return view(cache_k), view(cache_v)
 
 
 def _paged_update_attend(
@@ -144,12 +148,12 @@ def _paged_update_attend(
     from ..kernels import ops as kernel_ops
 
     B = q_heads.shape[0]
-    nb, bs = cache_k.shape[0], cache_k.shape[1]
+    nb, bs = cache_k.shape[0], cache_k.shape[2]
     blk = block_table[jnp.arange(B), pos // bs]  # (B,) pool block ids
     if active is not None:
         blk = jnp.where(active, blk, nb)  # OOB => write drops
-    cache_k = cache_k.at[blk, pos % bs].set(k_row.astype(cache_k.dtype), mode="drop")
-    cache_v = cache_v.at[blk, pos % bs].set(v_row.astype(cache_v.dtype), mode="drop")
+    cache_k = cache_k.at[blk, :, pos % bs].set(k_row.astype(cache_k.dtype), mode="drop")
+    cache_v = cache_v.at[blk, :, pos % bs].set(v_row.astype(cache_v.dtype), mode="drop")
     qh = q_heads.reshape(B, n_kv, -1, head_dim)
     if use_kernel:
         pos_eff = pos if active is None else jnp.where(active, pos, -1)
@@ -189,13 +193,12 @@ def _paged_attend_sharded(
     the causal bound like any stale entry.  Returns None when lanes and
     blocks do not co-shard (caller falls back to the GSPMD path)."""
     from ..dist import sharding as shardrules
-    from ..dist.collectives import shard_map_compat
     from jax.sharding import PartitionSpec as P
 
     B = q_heads.shape[0]
     nb = cache_k.shape[0]
     pool_spec = shardrules.paged_block_spec(cache_k.shape, mesh)
-    blk_ax, kv_ax = pool_spec[0], pool_spec[2]
+    blk_ax, kv_ax = pool_spec[0], pool_spec[1]
     lane_ax = shardrules.dp_axes(mesh, B)
     if blk_ax is None or lane_ax != blk_ax:
         return None
@@ -229,14 +232,15 @@ def _paged_attend_sharded(
             x_dtype=x_dtype,
         )
 
-    f = shard_map_compat(
-        local, mesh,
+    f = jax.shard_map(
+        local, mesh=mesh,
         in_specs=(
             P(lane_ax, kv_ax, None, None), P(lane_ax, kv_ax, None),
             P(lane_ax, kv_ax, None), pool_spec, pool_spec,
             P(lane_ax, None), P(lane_ax), P(lane_ax),
         ),
         out_specs=(P(lane_ax, kv_ax, None, None), pool_spec, pool_spec),
+        check_vma=False,
     )
     return f(q4, k_row, v_row, cache_k, cache_v, block_table, pos, active)
 
@@ -268,10 +272,10 @@ def decode_attention(
     would otherwise clobber).
 
     ``block_table`` switches the cache to PAGED layout: cache_[kv] is a
-    global pool of fixed-size blocks ``(n_blocks, block_size, K, d)``
+    global pool of fixed-size blocks ``(n_blocks, K, block_size, d)``
     shared by every lane, and ``block_table`` is (B, blocks_per_lane)
     int32 mapping each lane's logical block index to its pool block.
-    Lane b's logical row ``r`` lives at ``[table[b, r // bs], r % bs]``;
+    Lane b's logical row ``r`` lives at ``[table[b, r // bs], :, r % bs]``;
     the decode write scatters through the table (inactive lanes are
     redirected to the out-of-bounds block ``n_blocks`` so their writes
     drop — an inactive lane's table row may hold stale or unallocated
@@ -472,7 +476,7 @@ def prefill_chunk_attention(
     neg = jnp.asarray(NEG_INF, scores_dtype)
     if not ring:
         if block_table is not None:
-            nb, bs = cache_k.shape[0], cache_k.shape[1]
+            nb, bs = cache_k.shape[0], cache_k.shape[2]
             nb_lane = block_table.shape[1]
             bi = jnp.clip(qpos // bs, 0, nb_lane - 1)  # (B, C) logical blocks
             blk = jnp.take_along_axis(block_table, bi, axis=1)
@@ -480,8 +484,8 @@ def prefill_chunk_attention(
             # pads and the idle lanes' start=max_len sentinel rows drop
             ok = (jnp.arange(C)[None, :] < n_valid[:, None]) & (qpos < nb_lane * bs)
             blk = jnp.where(ok, blk, nb)
-            cache_k = cache_k.at[blk, qpos % bs].set(k.astype(cache_k.dtype), mode="drop")
-            cache_v = cache_v.at[blk, qpos % bs].set(v.astype(cache_v.dtype), mode="drop")
+            cache_k = cache_k.at[blk, :, qpos % bs].set(k.astype(cache_k.dtype), mode="drop")
+            cache_v = cache_v.at[blk, :, qpos % bs].set(v.astype(cache_v.dtype), mode="drop")
             keys, vals = _pool_gather(cache_k, cache_v, block_table, n_kv, head_dim)
         else:
             bidx = jnp.arange(B)[:, None]

@@ -176,7 +176,7 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     model-sharded projection.  The half-split form slices/concats across
     the sharded axis, which XLA's CPU SPMD partitioner handles via
     "involuntary full rematerialization" — and miscompiles (wrong values,
-    observed on jax 0.4.37 with hd sharded and batch replicated).  Both
+    observed with hd sharded and batch replicated).  Both
     conventions are valid RoPE; all call sites (train/prefill/decode)
     share this one, so caches stay consistent.
     """

@@ -13,6 +13,7 @@ training ``labels (B,S)``.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Any, Dict, Optional, Tuple
 
@@ -100,6 +101,48 @@ def init_params(key, cfg: ModelConfig) -> Params:
     params["final_norm"] = rmsnorm_init(cfg.d_model)
     if not cfg.tie_embeddings:
         params["lm_head"] = dense_init(k_head, cfg.d_model, cfg.padded_vocab, scale=0.02)
+    return params
+
+
+def init_packed_params(key, cfg: ModelConfig, n_bits: int) -> Params:
+    """``core.packing.pack_model_params(init_params(key, cfg), n_bits)``
+    without ever holding the float model: each superblock (and tail
+    layer) is drawn and packed by one jitted program, then the packed
+    slices are stacked.  Peak device memory is about twice the packed
+    tree plus one float superblock, so a 2.5 B-parameter model packs on
+    one 16 GB chip, where its float32 init alone takes 10 GB."""
+    from ..core.packing import PackedWeight, pack_model_params
+
+    k_emb, k_blocks, k_tail, k_head = jax.random.split(key, 4)
+    # Wrapped under the same top-level names as init_params so
+    # pack_model_params makes the same per-leaf packing decisions.
+    pack_sb = jax.jit(lambda k: pack_model_params(
+        {"blocks": _init_superblock(k, cfg)}, n_bits)["blocks"])
+    nb = cfg.n_superblocks
+    parts = [pack_sb(k) for k in jax.random.split(k_blocks, nb)]
+
+    def stack(*leaves):
+        if isinstance(leaves[0], PackedWeight):  # per-slice scale -> lead + (1, 1)
+            out = jax.tree.map(lambda *xs: jnp.stack(xs), *leaves)
+            return dataclasses.replace(out, scale=out.scale.reshape(nb, 1, 1))
+        return jnp.stack(leaves)
+
+    is_pw = lambda x: isinstance(x, PackedWeight)
+    params: Params = {"embed": embed_init(k_emb, cfg.padded_vocab, cfg.d_model)}
+    params["blocks"] = jax.tree.map(stack, *parts, is_leaf=is_pw)
+    del parts
+    if cfg.n_tail_layers:
+        tkeys = jax.random.split(k_tail, cfg.n_tail_layers)
+        params["tail"] = [
+            jax.jit(lambda k, kind=cfg.layer_pattern[i]: pack_model_params(
+                {"tail": [_init_layer(k, cfg, kind)]}, n_bits)["tail"][0])(tkeys[i])
+            for i in range(cfg.n_tail_layers)
+        ]
+    params["final_norm"] = rmsnorm_init(cfg.d_model)
+    if not cfg.tie_embeddings:
+        params["lm_head"] = pack_model_params(
+            {"lm_head": dense_init(k_head, cfg.d_model, cfg.padded_vocab, scale=0.02)},
+            n_bits)["lm_head"]
     return params
 
 
@@ -240,8 +283,9 @@ def _init_layer_cache(cfg: ModelConfig, kind: str, batch: int, max_len: int, dty
         if paged_blocks is not None:
             # paged layout: a global pool of fixed-size blocks shared by
             # every lane; the per-lane block table (owned by the slot
-            # pool) maps logical rows onto it
-            shape = (paged_blocks, block_size, cfg.n_kv_heads, hd)
+            # pool) maps logical rows onto it.  Head-major blocks so a
+            # (block_size, hd) tile per KV head is one Pallas block.
+            shape = (paged_blocks, cfg.n_kv_heads, block_size, hd)
         else:
             shape = (batch, max_len, cfg.n_kv_heads, hd)
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}

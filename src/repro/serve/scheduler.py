@@ -877,6 +877,17 @@ class ContinuousScheduler:
     def compiled_decode_programs(self) -> int:
         return int(self._decode._cache_size())
 
+    def decode_program_text(self) -> str:
+        """Optimized HLO of the pooled decode program, compiled for the
+        pool's current operands: what the device really runs (a Pallas
+        kernel shows as ``tpu_custom_call``)."""
+        pool = self.pool
+        args = (self.engine.params, pool.cache, pool.tok, pool.pos, pool.act,
+                pool.block_table)
+        if self._tiered:
+            args += (jnp.int32(self._n_bits),)
+        return self._decode.lower(*args).compile().as_text()
+
     def compiled_prefill_programs(self) -> int:
         """Prefill-side compiled programs: legacy admission compiles one
         per distinct prompt length (grows with the workload); chunked

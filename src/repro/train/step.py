@@ -326,7 +326,7 @@ def make_compressed_bsq_dp_step(
     ``state = add_residuals(state)`` once on a state built by
     :func:`init_bsq_state` before the first step.
     """
-    from ..dist.collectives import init_residuals, shard_map_compat, tree_compressed_psum_ef
+    from ..dist.collectives import init_residuals, tree_compressed_psum_ef
     from jax.sharding import PartitionSpec as P
 
     n_dp = mesh.shape[axis]
@@ -345,10 +345,10 @@ def make_compressed_bsq_dp_step(
         new_residual = jax.tree.map(lambda r: r[None], new_residual)
         return loss, metrics, grads, new_residual
 
-    sharded = shard_map_compat(
-        per_shard, mesh,
+    sharded = jax.shard_map(
+        per_shard, mesh=mesh,
         in_specs=(P(), P(), P(axis), P(axis)),
-        out_specs=(P(), P(), P(), P(axis)),
+        out_specs=(P(), P(), P(), P(axis)), check_vma=False,
     )
 
     def train_step(state, batch):

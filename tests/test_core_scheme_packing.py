@@ -70,3 +70,37 @@ def test_hbm_bytes_scales_with_precision():
     bf16 = 256 * 256 * 2
     assert b3 < b8 < bf16
     np.testing.assert_allclose(b3 / bf16, (3 + 1) / 16, rtol=0.05)
+
+
+@pytest.mark.parametrize("arch", ["granite-3-2b", "recurrentgemma-9b", "qwen2-moe-a2.7b"])
+def test_init_packed_params_equals_pack_of_init(arch):
+    """The streaming packed init (one jitted program per superblock, so a
+    published-width model packs on one chip) builds the tree the float
+    init followed by pack_model_params builds: same structure, shapes,
+    dtypes and float leaves, and packed weights within one quantisation
+    step (a fused jitted draw may differ from the op-by-op one in the
+    last ulp, which can move a code that sits on a rounding boundary)."""
+    from repro.configs import reduced_config
+    from repro.core.packing import PackedWeight, pack_model_params
+    from repro.models import init_packed_params, init_params
+
+    cfg = reduced_config(arch)
+    key = jax.random.PRNGKey(7)
+    want = pack_model_params(init_params(key, cfg), 6)
+    got = init_packed_params(key, cfg, 6)
+    is_pw = lambda x: isinstance(x, PackedWeight)
+    assert jax.tree.structure(got, is_leaf=is_pw) == jax.tree.structure(want, is_leaf=is_pw)
+    n_packed = 0
+    for g, w in zip(jax.tree.leaves(got, is_leaf=is_pw), jax.tree.leaves(want, is_leaf=is_pw)):
+        if is_pw(w):
+            n_packed += 1
+            assert (g.planes.shape, g.sign.shape, g.scale.shape) == (
+                w.planes.shape, w.sign.shape, w.scale.shape)
+            np.testing.assert_allclose(np.asarray(g.scale), np.asarray(w.scale), rtol=1e-6)
+            step = np.asarray(w.scale) / (2**6 - 1)
+            diff = np.abs(np.asarray(unpack_to_float(g)) - np.asarray(unpack_to_float(w)))
+            assert np.all(diff <= step * 1.001)
+        else:
+            assert g.shape == w.shape and g.dtype == w.dtype
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-6, atol=1e-7)
+    assert n_packed > 0

@@ -35,12 +35,13 @@ def test_engine_serves_on_2x4_mesh():
     engine token-for-token (greedy decoding is layout-invariant)."""
     out = _run_subprocess("""
         import jax, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import reduced_config
         from repro.models import init_params
         from repro.serve import Request, ServeEngine
         cfg = reduced_config("granite-3-2b")
         params = init_params(jax.random.PRNGKey(0), cfg)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         def reqs():
             return [Request(uid=i, tokens=(np.arange(8, dtype=np.int32) + i) % cfg.vocab_size,
                             max_new=6) for i in range(4)]
@@ -68,6 +69,7 @@ def test_packed_decode_on_2x4_mesh_matches_single_device():
     (slice-then-pack == pack-then-slice)."""
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import reduced_config
         from repro.core import export_packed, export_packed_sharded
         from repro.core.bitrep import decompose
@@ -77,7 +79,7 @@ def test_packed_decode_on_2x4_mesh_matches_single_device():
         cfg = reduced_config("granite-3-2b")
         params = init_params(jax.random.PRNGKey(0), cfg)
         packed = pack_model_params(params, 6)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         def reqs():
             return [Request(uid=i, tokens=(np.arange(4 + 2 * i, dtype=np.int32) + i)
                             % cfg.vocab_size, max_new=5) for i in range(5)]

@@ -26,6 +26,7 @@ def _run_subprocess(code: str, devices: int = 8) -> str:
 def test_ckpt_on_mesh_a_restores_on_mesh_b_token_exact(tmp_path):
     out = _run_subprocess(f"""
         import jax, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.ckpt import checkpoint as ckpt
         from repro.configs import reduced_config
         from repro.dist.elastic import reshard_tree
@@ -40,13 +41,13 @@ def test_ckpt_on_mesh_a_restores_on_mesh_b_token_exact(tmp_path):
                             % cfg.vocab_size, max_new=6) for i in range(4)]
 
         # Mesh A: shard, serve, checkpoint (ckpt stores logically-unsharded).
-        mesh_a = jax.make_mesh((2, 4), ("data", "model"))
+        mesh_a = make_mesh((2, 4), ("data", "model"))
         params_a = reshard_tree(params, mesh_a)
         ref = ServeEngine(params_a, cfg, max_len=32, mesh=mesh_a).generate(reqs())
         ckpt.save(params_a, r"{tmp_path}", step=7, shards=2)
 
         # Mesh B (different shape): restore with mesh= -> reshard_tree path.
-        mesh_b = jax.make_mesh((4, 2), ("data", "model"))
+        mesh_b = make_mesh((4, 2), ("data", "model"))
         params_b = ckpt.restore(jax.eval_shape(lambda: params), r"{tmp_path}",
                                 step=7, mesh=mesh_b)
         moved = ServeEngine(params_b, cfg, max_len=32, mesh=mesh_b).generate(reqs())

@@ -21,8 +21,8 @@ def _case(seed, *, B=3, n_kv=2, G=2, d=16, bs=4, nb_lane=6, dtype=jnp.float32):
     rng = np.random.default_rng(seed)
     n_blocks = B * nb_lane + 2  # a couple of never-referenced pool blocks
     q = jnp.asarray(rng.normal(size=(B, n_kv, G, d)), dtype)
-    k_pool = jnp.asarray(rng.normal(size=(n_blocks, bs, n_kv, d)), dtype)
-    v_pool = jnp.asarray(rng.normal(size=(n_blocks, bs, n_kv, d)), dtype)
+    k_pool = jnp.asarray(rng.normal(size=(n_blocks, n_kv, bs, d)), dtype)
+    v_pool = jnp.asarray(rng.normal(size=(n_blocks, n_kv, bs, d)), dtype)
     table = jnp.asarray(
         rng.permutation(n_blocks)[: B * nb_lane].reshape(B, nb_lane), jnp.int32)
     return q, k_pool, v_pool, table
@@ -105,8 +105,8 @@ def test_bf16_cache():
     resolution."""
     rng = np.random.default_rng(5)
     q = jnp.asarray(rng.normal(size=(2, 2, 2, 16)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(14, 4, 2, 16)), jnp.bfloat16)
-    v = jnp.asarray(rng.normal(size=(14, 4, 2, 16)), jnp.bfloat16)
+    k = jnp.asarray(rng.normal(size=(14, 2, 4, 16)), jnp.bfloat16)
+    v = jnp.asarray(rng.normal(size=(14, 2, 4, 16)), jnp.bfloat16)
     tbl = jnp.asarray(rng.permutation(14)[:12].reshape(2, 6), jnp.int32)
     pos = jnp.asarray([20, 6], jnp.int32)
     got = ops.paged_attention(q, k, v, tbl, pos, use_pallas=True, interpret=True)
@@ -121,13 +121,13 @@ def test_ref_matches_dense_softmax():
     rng = np.random.default_rng(6)
     B, KV, G, d, bs, nb = 2, 2, 2, 8, 4, 3
     q = jnp.asarray(rng.normal(size=(B, KV, G, d)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(B * nb, bs, KV, d)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(B * nb, bs, KV, d)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(B * nb, KV, bs, d)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(B * nb, KV, bs, d)), jnp.float32)
     tbl = jnp.arange(B * nb, dtype=jnp.int32).reshape(B, nb)
     pos = jnp.asarray([bs * nb - 1, 5], jnp.int32)
     out = paged_attention_ref(q, k, v, tbl, pos)
-    keys = k.reshape(B, nb * bs, KV, d)
-    vals = v.reshape(B, nb * bs, KV, d)
+    keys = k.reshape(B, nb, KV, bs, d).transpose(0, 1, 3, 2, 4).reshape(B, nb * bs, KV, d)
+    vals = v.reshape(B, nb, KV, bs, d).transpose(0, 1, 3, 2, 4).reshape(B, nb * bs, KV, d)
     for b in range(B):
         for kv in range(KV):
             for g in range(G):

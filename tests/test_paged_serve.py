@@ -440,13 +440,14 @@ def test_paged_packed_decode_on_2x4_mesh_matches_single_device():
     out = subprocess.run(
         [sys.executable, "-c", textwrap.dedent("""
             import jax, numpy as np
+            from repro.launch.mesh import make_mesh
             from repro.configs import reduced_config
             from repro.core.packing import pack_model_params
             from repro.models import init_params
             from repro.serve import Request, ServeEngine
             cfg = reduced_config("granite-3-2b")
             packed = pack_model_params(init_params(jax.random.PRNGKey(0), cfg), 6)
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             def reqs():
                 return [Request(uid=i, tokens=(np.arange(4 + 2 * i, dtype=np.int32) + i)
                                 % cfg.vocab_size, max_new=5) for i in range(5)]
@@ -461,7 +462,7 @@ def test_paged_packed_decode_on_2x4_mesh_matches_single_device():
                 pool = eng.scheduler.pool
                 assert pool.allocator.free_count == pool.n_blocks
                 assert eng.scheduler.compiled_decode_programs() == 1
-                kv = jax.tree.leaves(pool.cache)[0]  # (superblocks, n_blocks, bs, KV, hd)
+                kv = jax.tree.leaves(pool.cache)[0]  # (superblocks, n_blocks, KV, bs, hd)
                 assert not kv.sharding.is_fully_replicated, kv.sharding
                 assert kv.sharding.spec[1] == "data", kv.sharding.spec
                 # block tables co-shard with the pool: lanes over the data
@@ -494,13 +495,14 @@ def test_paged_overcommit_preemption_on_2x4_mesh_matches_single_device():
     out = subprocess.run(
         [sys.executable, "-c", textwrap.dedent("""
             import jax, numpy as np
+            from repro.launch.mesh import make_mesh
             from repro.configs import reduced_config
             from repro.core.packing import pack_model_params
             from repro.models import init_params
             from repro.serve import Request, ServeEngine
             cfg = reduced_config("granite-3-2b")
             packed = pack_model_params(init_params(jax.random.PRNGKey(0), cfg), 6)
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             def reqs():
                 return [Request(uid=i, tokens=(np.arange(4 + 2 * i, dtype=np.int32) + i)
                                 % cfg.vocab_size, max_new=5,
@@ -809,13 +811,14 @@ def test_spec_decode_on_2x4_mesh_matches_single_device():
     out = subprocess.run(
         [sys.executable, "-c", textwrap.dedent("""
             import jax, numpy as np
+            from repro.launch.mesh import make_mesh
             from repro.configs import reduced_config
             from repro.core.packing import pack_model_params
             from repro.models import init_params
             from repro.serve import Request, ServeEngine
             cfg = reduced_config("granite-3-2b")
             packed = pack_model_params(init_params(jax.random.PRNGKey(0), cfg), 6)
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             def reqs():
                 return [Request(uid=i, tokens=(np.arange(4 + 2 * i, dtype=np.int32) + i)
                                 % cfg.vocab_size, max_new=5) for i in range(5)]

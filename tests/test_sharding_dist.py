@@ -34,9 +34,10 @@ def _run_subprocess(code: str, devices: int = 8) -> str:
 def test_param_spec_rules_small_mesh():
     out = _run_subprocess("""
         import jax, json
+        from repro.launch.mesh import make_mesh
         from jax.sharding import PartitionSpec as P
         from repro.dist.sharding import param_spec, cache_spec
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         checks = []
         # col-parallel QKV: out-dim -> model, in-dim -> data
         checks.append(param_spec("blocks/p0/mixer/wq", (10, 8, 16), mesh) == P(None, "data", "model"))
@@ -71,6 +72,7 @@ def test_reduced_arch_lowers_on_8dev_mesh():
     compile + execution of one BSQ train step."""
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding
         from repro.configs import reduced_config
         from repro.core.bsq import BSQConfig
@@ -78,7 +80,7 @@ def test_reduced_arch_lowers_on_8dev_mesh():
         from repro.models.frontends import synthetic_batch
         from repro.optim import SGDM, step_decay
         from repro.train.step import init_bsq_state, make_bsq_train_step
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = reduced_config("granite-3-2b")
         opt = SGDM()
         state, ctx = init_bsq_state(jax.random.PRNGKey(0), cfg,
@@ -104,12 +106,13 @@ def test_compressed_dp_step_matches_plain():
     training over a few steps (bias removed by error feedback)."""
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import reduced_config
         from repro.models.frontends import synthetic_batch
         from repro.optim import SGDM, step_decay
         from repro.train.step import (init_plain_state, make_plain_train_step,
                                       make_compressed_dp_step)
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         cfg = reduced_config("granite-3-2b")
         opt = SGDM(weight_decay=0.0)
         lr = step_decay(0.05, [1000])
@@ -139,6 +142,7 @@ def test_reduced_arch_lowers_on_3axis_pod_mesh():
     sharding rules (batch over ("pod", "data")) beyond the dry-run."""
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding
         from repro.configs import reduced_config
         from repro.core.bsq import BSQConfig
@@ -146,7 +150,7 @@ def test_reduced_arch_lowers_on_3axis_pod_mesh():
         from repro.models.frontends import synthetic_batch
         from repro.optim import SGDM, step_decay
         from repro.train.step import init_bsq_state, make_bsq_train_step
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         assert dp_axes(mesh, 4) == ("pod", "data")  # batch spreads across pods
         cfg = reduced_config("granite-3-2b")
         opt = SGDM()
@@ -174,13 +178,14 @@ def test_compressed_bsq_dp_step_matches_plain_bsq():
     tree_compressed_psum_ef into the BSQ train step)."""
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import reduced_config
         from repro.core.bsq import BSQConfig
         from repro.models.frontends import synthetic_batch
         from repro.optim import SGDM, step_decay
         from repro.train.step import (init_bsq_state, make_bsq_train_step,
                                       make_compressed_bsq_dp_step)
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         cfg = reduced_config("granite-3-2b")
         bsq_cfg = BSQConfig(n_init=8, alpha=5e-3, compute_dtype=jnp.float32)
         opt = SGDM(weight_decay=0.0)
@@ -208,10 +213,11 @@ def test_compressed_bsq_dp_step_matches_plain_bsq():
 def test_elastic_reshard_between_meshes():
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.dist.elastic import reshard_tree, validate_batch_divisibility
         tree = {"blocks/p0/mixer/wq": jnp.arange(8*16, dtype=jnp.float32).reshape(8, 16)}
-        m1 = jax.make_mesh((2, 4), ("data", "model"))
-        m2 = jax.make_mesh((4, 2), ("data", "model"))
+        m1 = make_mesh((2, 4), ("data", "model"))
+        m2 = make_mesh((4, 2), ("data", "model"))
         t1 = reshard_tree(tree, m1)
         t2 = reshard_tree(t1, m2)
         np.testing.assert_array_equal(np.asarray(t2["blocks/p0/mixer/wq"]),
