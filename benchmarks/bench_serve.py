@@ -244,10 +244,10 @@ def ttft_stats(results):
 
 
 def tpot_stats(sched):
-    """(p50, p95) decode time-per-output-token in ms, from the
-    scheduler's ``serve_decode_step_ms`` histogram (same interpolation
-    as numpy.percentile over the retained reservoir)."""
-    h = sched.decode_ms_trace
+    """(p50, p95) in ms of the scheduler's step, the host time between a
+    decoding lane's tokens, from its ``serve_step_ms`` histogram (same
+    interpolation as numpy.percentile over the retained reservoir)."""
+    h = sched.obs.registry.histogram("serve_step_ms")
     return h.percentile(50), h.percentile(95)
 
 

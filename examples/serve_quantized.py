@@ -56,8 +56,7 @@ def main():
     reqs = [Request(uid=i, tokens=p, max_new=32) for i, p in enumerate(prompts)]
     results = engine.generate(reqs)
     for r in results[:3]:
-        print(f"req {r.uid}: prefill {r.prefill_ms:.1f} ms, "
-              f"{r.decode_ms_per_tok:.1f} ms/token -> {r.tokens[:10]}...")
+        print(f"req {r.uid}: prefill {r.prefill_ms:.1f} ms -> {r.tokens[:10]}...")
     toks = sum(len(r.tokens) for r in results)
     print(f"generated {toks} tokens across {len(results)} requests")
 

@@ -363,8 +363,7 @@ def main(argv=None):
     results = engine.generate(reqs, arrival_steps=arrivals) if args.continuous \
         else engine.generate(reqs)
     for r in sorted(results, key=lambda r: r.uid):
-        print(f"req {r.uid}: prefill {r.prefill_ms:.1f} ms, "
-              f"{r.decode_ms_per_tok:.2f} ms/tok, tokens={r.tokens[:8]}...")
+        print(f"req {r.uid}: prefill {r.prefill_ms:.1f} ms, tokens={r.tokens[:8]}...")
     total = sum(len(r.tokens) for r in results)
     print(f"{total} tokens generated")
     if args.continuous:
@@ -444,7 +443,7 @@ def _obs_smoke(args, obs, server, engine):
     families = parse_prometheus(text)  # raises on any malformed line
     required = ["serve_ttft_ms", "serve_requests_total"]
     if args.continuous:
-        required += ["serve_occupancy", "serve_decode_step_ms"]
+        required += ["serve_occupancy", "serve_step_ms", "serve_host_seconds_total"]
     if args.paged:
         required += ["serve_blocks_alloc_total", "serve_block_pool_free"]
     if args.spec_decode:

@@ -10,7 +10,9 @@ Three stdlib-only layers (import this package without jax installed):
 * :mod:`repro.obs.trace` — per-request span events, the
   :class:`FlightRecorder` ring of recent requests, JSONL +
   ``chrome://tracing`` dumps, and the single TTFT definition every
-  serve path derives ``Result.prefill_ms`` from.
+  serve path derives ``Result.prefill_ms`` from; and :class:`Span`, a
+  scheduler phase on the JAX profiler's clock and in the registry
+  (``jax.profiler`` is imported on first use).
 
 :mod:`repro.obs.quality` (imported lazily — it needs jax) probes a
 packed model's logit MSE / top-1 agreement at reduced active planes.
@@ -55,6 +57,12 @@ class Observability:
         self.registry = registry if registry is not None else Registry()
         self.recorder = (recorder if recorder is not None
                          else FlightRecorder(capacity=flight_capacity))
+
+    def span(self, phase: str, record) -> trace.Span:
+        """Context manager around one phase of the serving loop: the
+        profiler span ``serve.<phase>`` and ``record(seconds)`` on exit
+        (callers resolve ``record`` to a registry instrument once)."""
+        return trace.Span(f"serve.{phase}", record)
 
     def reset(self) -> None:
         """Zero metrics and drop traces (bench warmup)."""

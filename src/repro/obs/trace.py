@@ -40,8 +40,12 @@ timestamps relative to the recorder epoch) and
 https://ui.perfetto.dev -loadable document: one track per request with
 queued/prefill/decode slices and chunk instants).
 
-Stdlib-only; ``perf_counter`` is imported at module level so tests can
-monkeypatch the clock.
+:class:`Span` puts a phase of the scheduler's loop on the JAX profiler's
+clock and in the registry at once.
+
+Stdlib-only (a :class:`Span` imports ``jax.profiler`` when made);
+``perf_counter`` is imported at module level so tests can monkeypatch
+the clock.
 """
 from __future__ import annotations
 
@@ -93,6 +97,41 @@ def now() -> float:
     """The trace clock (monotonic seconds).  One function so every span
     start/stop — and the TTFT definition — reads the same clock."""
     return perf_counter()
+
+
+class Span:
+    """A phase of the serving loop on two clocks at once.
+
+    On enter it opens ``jax.profiler.TraceAnnotation(name)``, which the
+    profiler records on the trace's ``/host:CPU`` plane (on the device
+    trace's clock) only while a trace runs, and costs about a
+    microsecond otherwise.  On exit it hands ``record`` its inclusive
+    :func:`now` duration in seconds, so the registry accumulates the
+    same phases with no trace running.  JAX is imported on first use:
+    this module stays importable without it."""
+
+    __slots__ = ("annotation", "_record", "_t0")
+
+    def __init__(self, name: str, record):
+        from jax.profiler import TraceAnnotation
+
+        self.annotation = TraceAnnotation(name)
+        self._record = record
+
+    def annotate(self, **args) -> None:
+        """Attach ``args`` to the trace event (seen only while tracing)."""
+        self.annotation.set_metadata(**args)
+
+    def __enter__(self) -> "Span":
+        self.annotation.__enter__()
+        self._t0 = now()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        dt = now() - self._t0
+        self.annotation.__exit__(*exc)
+        self._record(dt)
+        return False
 
 
 @dataclasses.dataclass

@@ -52,7 +52,6 @@ for shardings.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Callable, Dict, List, Optional, Sequence
 
 import jax
@@ -92,7 +91,6 @@ class Result:
     # TTFT under the one definition every path shares: the request's
     # admitted -> first_token span (obs.trace.RequestTrace.ttft_ms).
     prefill_ms: float
-    decode_ms_per_tok: float
     # Tiered engines only: per-token active bit-plane count each token
     # was computed at, parallel to ``tokens`` (prefill's first token at
     # full precision, decode tokens at the step's effective count after
@@ -316,20 +314,17 @@ class ServeEngine:
         for r in bucket:
             rec.event(r.uid, obs_trace.FIRST_TOKEN, ts=t_first)
         out_toks = [tok]
-        t1 = time.perf_counter()
         for t in range(max_new - 1):
             logits, cache = self._decode(self.params, cache, tok[:, None], jnp.int32(plen + t))
             tok = self._sample(logits, temps, any_hot)
             out_toks.append(tok)
-        jax.block_until_ready(tok)
-        decode_ms = (time.perf_counter() - t1) * 1e3 / max(max_new - 1, 1)
         gen = np.asarray(jnp.stack(out_toks, axis=1))
         results = []
         for i, r in enumerate(bucket):
             tr = rec.finish(r.uid, obs_trace.FINISHED, n_tokens=r.max_new)
             c_req.labels(outcome="finished").inc()
             h_ttft.observe(tr.ttft_ms())
-            results.append(Result(r.uid, gen[i, : r.max_new], tr.ttft_ms(), decode_ms))
+            results.append(Result(r.uid, gen[i, : r.max_new], tr.ttft_ms()))
         return results
 
 

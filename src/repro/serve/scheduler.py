@@ -129,9 +129,15 @@ request gets a trace span (``enqueued -> admitted(slot[, blocks]) ->
 prefill_chunk* -> first_token -> decode_step* ->
 finished|abandoned|evicted``) in the flight recorder, and the per-step
 telemetry lands in bounded-reservoir histograms
-(``serve_occupancy`` / ``serve_decode_step_ms`` / ``serve_ttft_ms`` /
+(``serve_occupancy`` / ``serve_step_ms`` / ``serve_ttft_ms`` /
 the paged block gauges — capacity ``SchedulerPolicy.telemetry_capacity``)
-so a long-lived server holds O(capacity) memory.  ``Result.prefill_ms``
+so a long-lived server holds O(capacity) memory.  Each loop iteration
+is a ``serve.step`` span and its phases ``serve.admit`` /
+``serve.prefill`` / ``serve.decode`` / ``serve.finish`` (with
+``serve.blocks`` and ``serve.sync`` nested inside prefill and decode)
+are spans too (:class:`repro.obs.trace.Span`): on the JAX profiler's
+host plane while a trace runs, and always in ``serve_step_ms`` and
+``serve_host_seconds_total{phase}``.  ``Result.prefill_ms``
 reports TTFT as defined by :meth:`repro.obs.trace.RequestTrace.ttft_ms`
 — the ``admitted`` event (the wall clock at the admission burst that
 dequeued the request, so legacy admission includes the serialisation
@@ -142,7 +148,6 @@ catalogue and span schema live in docs/observability.md.
 from __future__ import annotations
 
 import dataclasses
-import time
 from collections import deque
 from typing import Callable, Deque, Dict, Iterator, List, Optional, Sequence, Tuple
 
@@ -157,6 +162,13 @@ from ..models.common import (active_plane_count, packed_shard_mesh,
 from ..obs import metrics as obs_metrics
 from ..obs import trace as obs_trace
 from .slots import SlotPool, SlotState, reset_recurrent_slots, scatter_slot
+
+# Phases of a scheduler step, each a ``serve.<phase>`` span and a child
+# of ``serve_host_seconds_total``.  ``blocks`` (block grants and the
+# block-table update) and ``sync`` (host reads of device results) nest
+# inside ``prefill`` and ``decode``; the legacy batch-1 admission's
+# sync nests inside ``admit``.
+PHASES = ("admit", "blocks", "prefill", "decode", "sync", "finish")
 
 
 @dataclasses.dataclass
@@ -624,9 +636,21 @@ class ContinuousScheduler:
         self._h_occ = reg.histogram(
             "serve_occupancy", "live decode lanes per pooled decode step",
             capacity=tcap)
-        self._h_step = reg.histogram(
-            "serve_decode_step_ms", "pooled decode step wall time (ms)",
+        # Host time of the loop: serve_step_ms per iteration (the time a
+        # consumer holds a yielded Result falls inside its step) and the
+        # inclusive seconds of each phase span.  The instruments are
+        # resolved here so a span costs no registry lookup.
+        h_step = reg.histogram(
+            "serve_step_ms",
+            "host wall time of one scheduler step (ms), a serve.step span",
             capacity=tcap)
+        host_s = reg.counter(
+            "serve_host_seconds_total",
+            "host seconds inside each serve.<phase> span of the scheduler "
+            "step, inclusive (blocks and sync nest in prefill and decode)",
+            labels=("phase",))
+        self._record = {p: host_s.labels(phase=p).inc for p in PHASES}
+        self._record["step"] = lambda seconds: h_step.observe(1e3 * seconds)
         self._h_ttft = reg.histogram(
             "serve_ttft_ms",
             "time to first token (admitted -> first_token span, ms)",
@@ -716,12 +740,10 @@ class ContinuousScheduler:
             "pool blocks read by decode attention per step", capacity=tcap)
         # Legacy names (bench/tests): the same bounded reservoirs.
         self.occupancy_trace = self._h_occ
-        self.decode_ms_trace = self._h_step
         self.block_used_trace = self._h_blocks
         self.live_rows_trace = self._h_rows
         self.attn_read_blocks_trace = self._h_attn
         self.admit_bursts = obs_metrics.Ring(tcap)
-        self.decode_ms_total = 0.0
         self.decode_steps = 0
         self.prefill_chunks = 0
         # Spec-decode scalar telemetry (bench/CI reads these directly).
@@ -1168,7 +1190,8 @@ class ContinuousScheduler:
                 jnp.asarray([req.temperature], jnp.float32),
                 req.temperature > 0,
             )
-            first_host = int(np.asarray(first)[0])
+            with self._span("sync"):
+                first_host = int(np.asarray(first)[0])
             tr.event(obs_trace.FIRST_TOKEN)
             ttft_ms = tr.ttft_ms()
             self._h_ttft.observe(ttft_ms)
@@ -1326,10 +1349,10 @@ class ContinuousScheduler:
                 demand.pop(victim, None)
         return demand
 
-    def _prefill_step(self, queue: Deque[_Pending], now: int):
+    def _prefill_step(self, queue: Deque[_Pending], now: int) -> Tuple[int, int]:
         """One prefill_chunk dispatch: every prefilling lane consumes up to
         C prompt tokens; lanes whose prompt completes sample their first
-        token and flip to the decode phase."""
+        token and flip to the decode phase.  Returns (lanes, C)."""
         pool = self.pool
         # Under overcommit the headroom pass may preempt lanes — including
         # prefilling ones, which changes the lane set and the chunk-size
@@ -1337,7 +1360,7 @@ class ContinuousScheduler:
         while True:
             lanes = pool.prefilling()
             if not lanes:
-                return  # every prefilling lane was preempted this step
+                return 0, 0  # every prefilling lane was preempted this step
             remaining = {
                 i: len(pool.slots[i].prompt) - pool.slots[i].filled
                 for i in lanes
@@ -1348,12 +1371,13 @@ class ContinuousScheduler:
             demand = {
                 i: pool.slots[i].filled + min(C, remaining[i]) for i in lanes
             }
-            if self._ensure_headroom(demand, queue, now) == demand:
-                # alloc-on-demand: grant the blocks each lane's chunk rows
-                # [filled, filled + take) land in before dispatch (one
-                # batched table update for the whole chunk)
-                pool.grow_many(demand)
-                break
+            with self._span("blocks"):
+                if self._ensure_headroom(demand, queue, now) == demand:
+                    # alloc-on-demand: grant the blocks each lane's chunk
+                    # rows [filled, filled + take) land in before dispatch
+                    # (one batched table update for the whole chunk)
+                    pool.grow_many(demand)
+                    break
         toks = np.zeros((pool.n_slots, C), np.int32)
         # Non-prefilling lanes point past the cache: every write drops and
         # n_valid=0 makes their recurrence a no-op (see prefill_chunk).
@@ -1377,7 +1401,8 @@ class ContinuousScheduler:
         sampled_host = None
         if done:
             sampled = self.engine._sample(last_logits, pool.temps, pool.any_hot)
-            sampled_host = np.asarray(sampled)
+            with self._span("sync"):
+                sampled_host = np.asarray(sampled)
         self.prefill_chunks += 1
         self._c_chunks.inc()
         rec = self.obs.recorder
@@ -1403,10 +1428,12 @@ class ContinuousScheduler:
                     # The first token comes off the full-precision
                     # prefill chunk, whatever the lane's tier.
                     s.plane_log = [self._n_bits]
+        return len(lanes), C
 
     # -- speculative decoding ----------------------------------------------
-    def _spec_round(self, queue: Deque[_Pending], now: int) -> None:
-        """One draft+verify round over every decode-phase lane.
+    def _spec_round(self, queue: Deque[_Pending], now: int) -> int:
+        """One draft+verify round over every decode-phase lane; returns
+        how many lanes it decoded.
 
         Lane ``i`` at ``pos0 = plen + g - 1`` (last token ``d_0`` sampled
         but its KV row unwritten — the pool's steady-state convention)
@@ -1435,7 +1462,7 @@ class ContinuousScheduler:
             lanes = [i for i, s in enumerate(pool.slots)
                      if s.uid is not None and s.phase == "decode"]
             if not lanes:
-                return  # every decode lane was preempted this step
+                return 0  # every decode lane was preempted this step
             gam: Dict[int, int] = {}
             demand: Dict[int, int] = {}
             for i in lanes:
@@ -1446,9 +1473,10 @@ class ContinuousScheduler:
                 # remaining keeps this within the lifetime reservation
                 # (the headroom/deadlock-freedom argument is unchanged).
                 demand[i] = len(s.prompt) + len(s.tokens) + gam[i] - 1
-            if self._ensure_headroom(demand, queue, now) == demand:
-                pool.grow_many(demand)
-                break
+            with self._span("blocks"):
+                if self._ensure_headroom(demand, queue, now) == demand:
+                    pool.grow_many(demand)
+                    break
         gamma_r = max(gam.values())
         B = pool.n_slots
         act_rows = np.zeros((gamma_r, B), np.bool_)
@@ -1460,7 +1488,6 @@ class ContinuousScheduler:
             start[i] = len(s.prompt) + len(s.tokens) - 1  # pos0
             nval[i] = gam[i]
         self._h_attn.observe(sum(len(pool.slots[i].blocks) for i in lanes))
-        t0 = time.perf_counter()
         draft_fn = self._spec_draft_fn()
         verify_fn = self._spec_verify_fn()
         params = self.engine.params
@@ -1500,8 +1527,8 @@ class ContinuousScheduler:
         pool.cache, verified = verify_fn(*vargs)
         # drafts_h[j][i] = d_{j+1} for lane i; ver_h[i, j] = v_j (columns
         # past gam[i] are padding and never read).
-        drafts_h, ver_h = jax.device_get((drafts, verified))
-        step_ms = (time.perf_counter() - t0) * 1e3
+        with self._span("sync"):
+            drafts_h, ver_h = jax.device_get((drafts, verified))
         rec = self.obs.recorder
         tok_fix, tok_vals, pos_vals = [], [], []
         acc_total = rej_total = commit_total = 0
@@ -1557,8 +1584,6 @@ class ContinuousScheduler:
         pool.tok = pool._pin("tok", tok)
         pool.pos = pool._pin("pos", pos)
         # One round = one pooled dispatch on the decode clock.
-        self.decode_ms_total += step_ms
-        self._h_step.observe(step_ms)
         self.decode_steps += 1
         self._c_steps.inc()
         self.spec_rounds += 1
@@ -1578,6 +1603,7 @@ class ContinuousScheduler:
         self._h_rows.observe(live)
         if used:
             self._h_frag.observe(1.0 - live / (used * pool.block_size))
+        return len(lanes)
 
     # -- main loop ---------------------------------------------------------
     def stream(
@@ -1675,143 +1701,59 @@ class ContinuousScheduler:
         now = 0
         try:
             while incoming or queue or pool.n_active:
-                while incoming and incoming[0].arrival <= now:
-                    pend = incoming.popleft()
-                    pend.enqueued_at = now
-                    rec.begin(pend.request.uid, arrival=pend.arrival)
-                    queue.append(pend)
-                self._g_queue.set(len(queue))
-                self._admit(queue, now)
-                if self._tiered and self.policy.degrade:
-                    # Load-triggered plane shedding: measured AFTER the
-                    # admission pass, so "queue backed up" means work
-                    # that genuinely could not be placed this step.
-                    self._degrade_tick(len(queue), now)
-                # Evict lanes whose request finished at admission
-                # (legacy max_new == 1).
-                for ev in self._finished():
-                    yield ev
-                worked = False
-                if self.policy.chunked_prefill and pool.prefilling():
-                    self._prefill_step(queue, now)
-                    worked = True
-                    # chunked max_new == 1: finished at first token
-                    for ev in self._finished():
-                        yield ev
-                if self.policy.spec_decode and pool.n_decoding:
-                    # Speculative rounds replace the single pooled decode
-                    # step: gamma draft steps + one verify per dispatch,
-                    # committing 1..gamma tokens per lane (block growth,
-                    # headroom preemption and rewind live inside).
-                    worked = True
-                    self._spec_round(queue, now)
-                    for ev in self._finished():
-                        yield ev
-                elif pool.n_decoding:
-                    worked = True
-                    if self.policy.paged:
-                        # decode growth: lanes crossing a block boundary
-                        # need their next block granted before the write
-                        # (one batched table update for the whole step).
-                        # Under overcommit the headroom pass may first
-                        # preempt victims — possibly every decode lane —
-                        # so the dispatch below re-checks n_decoding.
-                        pool.grow_many(self._ensure_headroom({
-                            i: len(s.prompt) + len(s.tokens)
-                            for i, s in enumerate(pool.slots)
-                            if s.uid is not None and s.phase == "decode"
-                        }, queue, now))
-                        # blocks this step's attention actually reads: the
-                        # decode lanes' live blocks (== the paged kernel's
-                        # per-step HBM traffic; the gather path reads
-                        # blocks_per_lane per live lane regardless)
-                        self._h_attn.observe(sum(
-                            len(s.blocks) for s in pool.slots
-                            if s.uid is not None and s.phase == "decode"
-                        ))
-                if not self.policy.spec_decode and pool.n_decoding:
-                    t0 = time.perf_counter()
-                    active = pool.decode_mask  # lanes live during this decode step
-                    if self._tiered:
-                        # Group the step's decode lanes by effective plane
-                        # count and run one pooled dispatch per distinct
-                        # count (grouping off: one dispatch at the max
-                        # count serves every lane).  Still ONE compiled
-                        # program — the count is a traced operand and the
-                        # group's act mask is data.  Each group's sampled
-                        # tokens merge into tok/pos under its own mask, so
-                        # a later group's dispatch cannot clobber an
-                        # earlier group's pending token.
-                        eff = {i: self._effective_planes(pool.slots[i])
-                               for i in range(pool.n_slots) if active[i]}
-                        if self.policy.plane_grouping:
-                            groups: Dict[int, List[int]] = {}
-                            for i, k in eff.items():
-                                groups.setdefault(k, []).append(i)
-                        else:
-                            groups = {max(eff.values()): sorted(eff)}
-                        sampled_host = np.zeros((pool.n_slots,), np.int32)
-                        lane_planes: Dict[int, int] = {}
-                        # Descending plane order: deterministic, and the
-                        # costliest group goes first.
-                        for k in sorted(groups, reverse=True):
-                            gmask = np.zeros((pool.n_slots,), np.bool_)
-                            gmask[groups[k]] = True
-                            act_g = pool._pin("act", jnp.asarray(gmask))
-                            logits, pool.cache = self._decode(
-                                self.engine.params, pool.cache, pool.tok,
-                                pool.pos, act_g, pool.block_table,
-                                jnp.int32(k),
-                            )
-                            sampled = self.engine._sample(
-                                logits, pool.temps, pool.any_hot)
-                            pool.tok = pool._pin("tok", jnp.where(
-                                jnp.asarray(gmask)[:, None],
-                                sampled[:, None], pool.tok))
-                            g_host = np.asarray(sampled)
-                            sampled_host[gmask] = g_host[gmask]
-                            for i in groups[k]:
-                                lane_planes[i] = k
-                    else:
-                        logits, pool.cache = self._decode(
-                            self.engine.params, pool.cache, pool.tok, pool.pos, pool.act,
-                            pool.block_table,
-                        )
-                        sampled = self.engine._sample(logits, pool.temps, pool.any_hot)
-                        sampled_host = np.asarray(sampled)  # one host sync per step (streaming)
-                        pool.tok = pool._pin("tok", sampled[:, None])
-                    step_ms = (time.perf_counter() - t0) * 1e3
-                    self.decode_ms_total += step_ms
-                    self._h_step.observe(step_ms)
-                    self.decode_steps += 1
-                    self._c_steps.inc()
-                    pool.advance(sampled_host, active)
-                    self._h_occ.observe(int(active.sum()))
-                    for i, s in enumerate(pool.slots):
-                        if active[i] and s.uid is not None:
-                            if self._tiered:
-                                s.plane_log.append(lane_planes[i])
-                                rec.event(s.uid, obs_trace.DECODE_STEP,
-                                          planes=lane_planes[i])
-                            else:
-                                rec.event(s.uid, obs_trace.DECODE_STEP)
-                    if self.policy.paged:
-                        used = pool.allocator.used_count
-                        live = pool.live_rows()
-                        self._h_blocks.observe(used)
-                        self._h_rows.observe(live)
-                        if used:
-                            self._h_frag.observe(
-                                1.0 - live / (used * pool.block_size))
-                    for ev in self._finished():
-                        yield ev
-                if not worked and incoming and not queue:
-                    # idle gap before the next arrival: fast-forward the
-                    # clock.  Only when the queue is empty — a HELD queue
-                    # (max-wait batching) must age step by step so the
-                    # max_wait deadline fires on time, not at next arrival.
-                    now = max(now, incoming[0].arrival - 1)
-                now += 1
+                # Results are yielded outside the phase spans: only
+                # serve.step holds the time a consumer keeps a yield.
+                with self._span("step") as step:
+                    with self._span("admit"):
+                        while incoming and incoming[0].arrival <= now:
+                            pend = incoming.popleft()
+                            pend.enqueued_at = now
+                            rec.begin(pend.request.uid, arrival=pend.arrival)
+                            queue.append(pend)
+                        self._g_queue.set(len(queue))
+                        self._admit(queue, now)
+                        if self._tiered and self.policy.degrade:
+                            # Load-triggered plane shedding: measured AFTER
+                            # the admission pass, so "queue backed up" means
+                            # work that genuinely could not be placed.
+                            self._degrade_tick(len(queue), now)
+                    # Evict lanes whose request finished at admission
+                    # (legacy max_new == 1).
+                    yield from self._retire()
+                    worked = False
+                    n_pre = chunk = n_dec = 0
+                    if self.policy.chunked_prefill and pool.prefilling():
+                        with self._span("prefill"):
+                            n_pre, chunk = self._prefill_step(queue, now)
+                        worked = True
+                        # chunked max_new == 1: finished at first token
+                        yield from self._retire()
+                    if self.policy.spec_decode and pool.n_decoding:
+                        # Speculative rounds replace the single pooled
+                        # decode step: gamma draft steps + one verify per
+                        # dispatch, committing 1..gamma tokens per lane
+                        # (block growth, headroom preemption and rewind
+                        # live inside).
+                        worked = True
+                        with self._span("decode"):
+                            n_dec = self._spec_round(queue, now)
+                        yield from self._retire()
+                    elif pool.n_decoding:
+                        worked = True
+                        with self._span("decode"):
+                            decoded = self._decode_step(queue, now)
+                        if decoded is not None:
+                            n_dec = int(decoded[1].sum())
+                            yield from self._retire(decoded)
+                    step.annotate(step=now, prefilling=n_pre, chunk=chunk, decoding=n_dec)
+                    if not worked and incoming and not queue:
+                        # idle gap before the next arrival: fast-forward
+                        # the clock.  Only when the queue is empty — a
+                        # HELD queue (max-wait batching) must age step by
+                        # step so the max_wait deadline fires on time,
+                        # not at next arrival.
+                        now = max(now, incoming[0].arrival - 1)
+                    now += 1
         finally:
             # An abandoned generator (client disconnect mid-stream, possibly
             # mid-PREFILL) must not leave ghost lanes: free every live lane —
@@ -1841,12 +1783,135 @@ class ContinuousScheduler:
             if self.policy.spec_decode:
                 self._g_progs.labels(kind="spec").set(self.compiled_spec_programs())
 
+    def _span(self, phase: str):
+        return self.obs.span(phase, self._record[phase])
+
+    def _decode_step(self, queue: Deque[_Pending], now: int):
+        """One pooled decode step over every decode-phase lane: block
+        growth, dispatch (one per plane group on tiered engines),
+        sampling and the step's one host sync.  Returns the sampled
+        tokens, the lane mask and each lane's plane count (tiered), or
+        None when the headroom pass preempted every decode lane."""
+        pool = self.pool
+        if self.policy.paged:
+            # decode growth: lanes crossing a block boundary need their
+            # next block granted before the write (one batched table
+            # update for the whole step).  Under overcommit the headroom
+            # pass may first preempt victims — possibly every decode lane
+            # — so the dispatch below re-checks n_decoding.
+            with self._span("blocks"):
+                pool.grow_many(self._ensure_headroom({
+                    i: len(s.prompt) + len(s.tokens)
+                    for i, s in enumerate(pool.slots)
+                    if s.uid is not None and s.phase == "decode"
+                }, queue, now))
+            # blocks this step's attention actually reads: the decode
+            # lanes' live blocks (== the paged kernel's per-step HBM
+            # traffic; the gather path reads blocks_per_lane per live
+            # lane regardless)
+            self._h_attn.observe(sum(
+                len(s.blocks) for s in pool.slots
+                if s.uid is not None and s.phase == "decode"
+            ))
+        if not pool.n_decoding:
+            return None
+        active = pool.decode_mask  # lanes live during this decode step
+        lane_planes: Dict[int, int] = {}
+        if self._tiered:
+            # Group the step's decode lanes by effective plane count and
+            # run one pooled dispatch per distinct count (grouping off:
+            # one dispatch at the max count serves every lane).  Still
+            # ONE compiled program — the count is a traced operand and
+            # the group's act mask is data.  Each group's sampled tokens
+            # merge into tok/pos under its own mask, so a later group's
+            # dispatch cannot clobber an earlier group's pending token.
+            eff = {i: self._effective_planes(pool.slots[i])
+                   for i in range(pool.n_slots) if active[i]}
+            if self.policy.plane_grouping:
+                groups: Dict[int, List[int]] = {}
+                for i, k in eff.items():
+                    groups.setdefault(k, []).append(i)
+            else:
+                groups = {max(eff.values()): sorted(eff)}
+            sampled_host = np.zeros((pool.n_slots,), np.int32)
+            # Descending plane order: deterministic, and the costliest
+            # group goes first.
+            for k in sorted(groups, reverse=True):
+                gmask = np.zeros((pool.n_slots,), np.bool_)
+                gmask[groups[k]] = True
+                act_g = pool._pin("act", jnp.asarray(gmask))
+                logits, pool.cache = self._decode(
+                    self.engine.params, pool.cache, pool.tok,
+                    pool.pos, act_g, pool.block_table,
+                    jnp.int32(k),
+                )
+                sampled = self.engine._sample(
+                    logits, pool.temps, pool.any_hot)
+                pool.tok = pool._pin("tok", jnp.where(
+                    jnp.asarray(gmask)[:, None],
+                    sampled[:, None], pool.tok))
+                with self._span("sync"):
+                    g_host = np.asarray(sampled)
+                sampled_host[gmask] = g_host[gmask]
+                for i in groups[k]:
+                    lane_planes[i] = k
+        else:
+            logits, pool.cache = self._decode(
+                self.engine.params, pool.cache, pool.tok, pool.pos, pool.act,
+                pool.block_table,
+            )
+            sampled = self.engine._sample(logits, pool.temps, pool.any_hot)
+            with self._span("sync"):  # one host sync per step (streaming)
+                sampled_host = np.asarray(sampled)
+            pool.tok = pool._pin("tok", sampled[:, None])
+        return sampled_host, active, lane_planes
+
+    def _retire(self, decoded=None):
+        """The step's ``finish`` phase: after a decode step (``decoded``
+        is what :meth:`_decode_step` returned) advance its lanes and
+        record it; then evict finished lanes one at a time, yielding
+        each Result outside the span.  A consumer that stops early
+        leaves the lanes not yet retired to the stream's clean-up."""
+        if decoded is not None:
+            with self._span("finish"):
+                self._record_decode(*decoded)
+        retired = self._finished()
+        while True:
+            with self._span("finish"):
+                res = next(retired, None)
+            if res is None:
+                return
+            yield res
+
+    def _record_decode(self, sampled_host: np.ndarray, active: np.ndarray,
+                       lane_planes: Dict[int, int]) -> None:
+        pool = self.pool
+        rec = self.obs.recorder
+        self.decode_steps += 1
+        self._c_steps.inc()
+        pool.advance(sampled_host, active)
+        self._h_occ.observe(int(active.sum()))
+        for i, s in enumerate(pool.slots):
+            if active[i] and s.uid is not None:
+                if self._tiered:
+                    s.plane_log.append(lane_planes[i])
+                    rec.event(s.uid, obs_trace.DECODE_STEP,
+                              planes=lane_planes[i])
+                else:
+                    rec.event(s.uid, obs_trace.DECODE_STEP)
+        if self.policy.paged:
+            used = pool.allocator.used_count
+            live = pool.live_rows()
+            self._h_blocks.observe(used)
+            self._h_rows.observe(live)
+            if used:
+                self._h_frag.observe(1.0 - live / (used * pool.block_size))
+
     def _finished(self):
         from .engine import Result
 
         pool = self.pool
         rec = self.obs.recorder
-        per_tok = self.decode_ms_total / max(self.decode_steps, 1)
         for i, s in enumerate(pool.slots):
             if s.uid is not None and s.phase == "decode" and s.remaining <= 0:
                 done = pool.evict(i)
@@ -1866,7 +1931,6 @@ class ContinuousScheduler:
                     uid=done.uid,
                     tokens=np.asarray(full, np.int32),
                     prefill_ms=done.prefill_ms,
-                    decode_ms_per_tok=per_tok,
                     plane_log=plane_log,
                 )
 
@@ -1884,7 +1948,6 @@ class ContinuousScheduler:
         self.obs.reset()
         self.admit_bursts.clear()
         self.prefill_chunks = 0
-        self.decode_ms_total = 0.0
         self.decode_steps = 0
         self.spec_rounds = 0
         self.spec_drafted = 0

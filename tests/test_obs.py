@@ -28,6 +28,7 @@ import pytest
 from repro.configs import reduced_config
 from repro.core.packing import pack_model_params, packed_leaves, unpack_to_float
 from repro.models import init_params
+from repro.obs import Observability
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.obs.export import (
@@ -379,6 +380,84 @@ def test_engines_never_share_obs_state(granite):
     b = ServeEngine(params, cfg, max_len=32)
     assert a.obs.registry is not b.obs.registry
     assert a.obs.recorder is not b.obs.recorder
+
+
+# ---------------------------------------------------------------------------
+# scheduler phase spans: profiler clock + registry
+# ---------------------------------------------------------------------------
+
+def test_span_adds_inclusive_seconds_to_its_instrument(monkeypatch):
+    """No profiler is running: the span still times its block, nested
+    spans each add their own inclusive duration, and an exception leaves
+    the block with its time recorded."""
+    obs = Observability()
+    clock = iter([10.0, 10.5, 11.0, 13.0, 20.0, 20.25])
+    monkeypatch.setattr(obs_trace, "perf_counter", lambda: next(clock))
+    fam = obs.registry.counter("serve_host_seconds_total", labels=("phase",))
+    decode, sync = fam.labels(phase="decode"), fam.labels(phase="sync")
+    with obs.span("decode", decode.inc):
+        with obs.span("sync", sync.inc) as s:
+            s.annotate(lanes=3)
+    assert sync.value == 0.5 and decode.value == 3.0
+    with pytest.raises(KeyError):
+        with obs.span("sync", sync.inc):
+            raise KeyError("lane")
+    assert sync.value == 0.75 and decode.value == 3.0
+
+
+def _paged_engine(granite):
+    cfg, params = granite
+    return ServeEngine(params, cfg, max_len=32, continuous=True,
+                       policy=SchedulerPolicy(n_slots=2, chunked_prefill=True,
+                                              chunk_sizes=(8, 1), paged=True,
+                                              block_size=4, n_blocks=16))
+
+
+def _span_reqs(cfg):
+    # prompts of 3-7 rows and 6-9 new tokens: every lane crosses blocks
+    rng = np.random.default_rng(5)
+    return [Request(uid=i, tokens=rng.integers(0, cfg.vocab_size, size=3 + i).astype(np.int32),
+                    max_new=6 + i % 4) for i in range(5)]
+
+
+def test_stream_times_every_step_and_its_phases(granite, monkeypatch):
+    eng = _paged_engine(granite)
+    sched = eng.scheduler
+    iterations = []
+    admit = sched._admit  # called once per loop iteration
+    monkeypatch.setattr(sched, "_admit", lambda *a: iterations.append(1) or admit(*a))
+    out = eng.generate(_span_reqs(granite[0]))
+    assert len(out) == 5
+    reg = eng.obs.registry
+    step = reg.histogram("serve_step_ms")
+    assert step.count == len(iterations) > 0
+    host = reg.counter("serve_host_seconds_total", labels=("phase",))
+    s = {p: host.labels(phase=p).value for p in
+         ("admit", "blocks", "prefill", "decode", "sync", "finish")}
+    assert s["admit"] + s["prefill"] + s["decode"] + s["finish"] <= step.sum / 1e3 + 1e-9
+    assert s["blocks"] + s["sync"] <= s["prefill"] + s["decode"] + 1e-9
+    assert s["blocks"] > 0 and s["sync"] > 0 and s["finish"] > 0
+
+
+def test_stream_spans_land_on_the_profiler_host_plane(granite, tmp_path):
+    eng = _paged_engine(granite)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        out = eng.generate(_span_reqs(granite[0]))
+    finally:
+        jax.profiler.stop_trace()
+    assert len(out) == 5
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    profile = jax.profiler.ProfileData.from_file(str(path))
+    events = [e for plane in profile.planes if plane.name.startswith("/host:CPU")
+              for line in plane.lines for e in line.events]
+    names = {e.name for e in events}
+    assert {"serve.step", "serve.admit", "serve.prefill", "serve.decode", "serve.blocks",
+            "serve.sync", "serve.finish"} <= names
+    steps = [e for e in events if e.name == "serve.step"]
+    assert len(steps) == eng.obs.registry.histogram("serve_step_ms").count
+    args = [name for name, _ in steps[-1].stats]
+    assert sorted(args) == ["chunk", "decoding", "prefilling", "step"]
 
 
 # ---------------------------------------------------------------------------

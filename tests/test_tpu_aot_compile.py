@@ -8,6 +8,13 @@ compiles catch such refusals with no chip.  Widths: d_model 2048, d_ff
 KV blocks.  Decode dispatches M = n_slots rows; a prefill chunk of C
 tokens dispatches M = n_slots * C.
 
+The benchmark reads a device trace by names: the programs' jitted
+function names (``jit__decode_fn``, ``jit_chunk_into_pool``) and the
+kernels' ``pallas_call`` names, which name their HLO instructions
+(``%bitserial_matmul_pallas.N``, ``%paged_attention_pallas.N``).  These
+tests pin both, so a rename fails here instead of silencing the
+benchmark's step times and rooflines.
+
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and test workers import every file.
 """
@@ -45,9 +52,11 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", was)
 
 
-def _compile(fn, *shapes):
+def _compile(fn, kernel, *shapes):
     compiled = jax.jit(fn).lower(*shapes).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert f"%{kernel}" in text  # the instruction name the trace is read by
     return compiled
 
 
@@ -68,10 +77,10 @@ def test_bitserial_matmul_compiles(one_chip, K, N, M, dynamic):
         a = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
         fn = lambda x, pw, a: ops.bitserial_matmul(
             x, pw, active_planes=a, use_pallas=True, interpret=False)
-        out = _compile(fn, x, pw, a)
+        out = _compile(fn, "bitserial_matmul_pallas", x, pw, a)
     else:
         fn = lambda x, pw: ops.bitserial_matmul(x, pw, use_pallas=True, interpret=False)
-        out = _compile(fn, x, pw)
+        out = _compile(fn, "bitserial_matmul_pallas", x, pw)
     assert out.out_info.shape == (M, N)
 
 
@@ -85,5 +94,26 @@ def test_paged_attention_compiles(one_chip, window):
     pos = s((N_SLOTS,), jnp.int32)
     fn = lambda q, k, v, t, p: ops.paged_attention(
         q, k, v, t, p, window=window, use_pallas=True, interpret=False)
-    out = _compile(fn, q, pool, pool, table, pos)
+    out = _compile(fn, "paged_attention_pallas", q, pool, pool, table, pos)
     assert out.out_info.shape == q.shape
+
+
+def test_serving_programs_keep_their_jitted_names():
+    """On the CPU: the paged engine's decode and chunk programs lower to
+    the modules the trace's ``XLA Modules`` line names."""
+    from repro.configs import reduced_config
+    from repro.models import init_params
+    from repro.serve import ServeEngine
+
+    cfg = reduced_config("granite-3-2b")
+    eng = ServeEngine(init_params(jax.random.PRNGKey(0), cfg), cfg, max_len=32,
+                      continuous=True, n_slots=2, chunked_prefill=True, paged=True,
+                      block_size=4, paged_kernel=True)
+    sched, pool = eng.scheduler, eng.scheduler.pool
+    decode = sched._decode.lower(eng.params, pool.cache, pool.tok, pool.pos, pool.act,
+                                 pool.block_table)
+    assert "module @jit__decode_fn " in decode.as_text()
+    ctrl = jnp.zeros((pool.n_slots,), jnp.int32)
+    chunk = sched._chunk_fn(1).lower(eng.params, pool.cache, ctrl[:, None], ctrl, ctrl,
+                                     pool.block_table)
+    assert "module @jit_chunk_into_pool " in chunk.as_text()
