@@ -7,9 +7,12 @@ holds ``n_slots`` persistent lanes; the loop is::
     while queue or active lanes:
         admit:   every placeable queued request claims a lane
         prefill: (chunked mode) ONE prefill_chunk dispatch advances every
-                 prefilling lane by up to C prompt tokens
+                 prefilling lane by up to C prompt tokens — unless C is 1,
+                 when the prefilling lanes fold into the decode dispatch
         decode:  ONE pooled decode step over all n_slots lanes, driven by
                  the per-slot position vector and the ``act`` phase mask
+                 (at C = 1 a prefilling lane feeds its next prompt token
+                 at its fill position, exactly a decode lane's work)
         sample:  per-lane greedy/temperature on the pooled logits
         evict:   lanes that hit max_new stream a Result out and free up —
                  the next admission joins mid-flight
@@ -30,7 +33,11 @@ Two prefill styles:
   per-lane phase keeps decoding lanes emitting tokens while prefilling
   lanes advance through their prompts, so a long prompt never
   head-of-line blocks live lanes.  The prefill compiled set is O(#chunk
-  sizes), independent of the workload's prompt-length mix.
+  sizes), independent of the workload's prompt-length mix.  A step
+  whose chunk size comes out at 1 (most steps of a busy pool) runs no
+  prefill program: its prefilling lanes join the step's one decode
+  dispatch, so the weights are read once per step (see
+  :meth:`ContinuousScheduler._decode_step`).
 
 Because the decode step's shapes never depend on the arrival pattern
 (always ``tok (n_slots, 1)``, ``pos (n_slots,)``, ``act (n_slots,)``),
@@ -596,6 +603,24 @@ class ContinuousScheduler:
                                                    block_table=table, paged_kernel=pk)
 
         self._decode = jax.jit(_decode_fn, out_shardings=out_sh)
+        # Chunk-1 prefill folds into the decode dispatch wherever a step
+        # runs exactly one plain decode program.  Spec rounds and tiered
+        # steps keep their own prefill dispatch: there the first token
+        # must come off full planes, not a draft or a tier's.
+        self._folds = (policy.chunked_prefill and not policy.spec_decode
+                       and not self._tiered)
+
+        def _fold_fn(tok, pos, act, ctrl):
+            # ctrl rows: lane mask, prompt token, fill position
+            lane = ctrl[0] > 0
+            return (jnp.where(lane[:, None], ctrl[1][:, None], tok),
+                    jnp.where(lane, ctrl[2], pos), act | lane)
+
+        fold_sh = None
+        if engine.mesh is not None:
+            sh = self.pool.shardings
+            fold_sh = (sh["tok"], sh["pos"], sh["act"])
+        self._fold_inputs = jax.jit(_fold_fn, out_shardings=fold_sh)
         self._prefill_cache: Dict[int, Callable] = {}  # legacy: per prompt length
         self._chunk_cache: Dict[int, Callable] = {}  # chunked: per chunk size
         # Spec decode: ONE draft-step program (round depth = dispatch
@@ -666,6 +691,13 @@ class ContinuousScheduler:
             "scheduler steps where a queued request could not be placed")
         self._c_chunks = reg.counter(
             "serve_prefill_chunks_total", "prefill_chunk dispatches")
+        ptok = reg.counter(
+            "serve_prefill_tokens_total",
+            "prompt tokens prefilled, by the program that consumed them: "
+            "the decode program (chunk-1 lanes folded into a decode step) "
+            "or a prefill_chunk program",
+            labels=("path",))
+        self._c_ptok = {p: ptok.labels(path=p) for p in ("decode", "chunk")}
         self._c_preempt = reg.counter(
             "serve_preemptions_total",
             "lanes preempted under overcommit pressure (blocks reclaimed, "
@@ -1352,7 +1384,12 @@ class ContinuousScheduler:
     def _prefill_step(self, queue: Deque[_Pending], now: int) -> Tuple[int, int]:
         """One prefill_chunk dispatch: every prefilling lane consumes up to
         C prompt tokens; lanes whose prompt completes sample their first
-        token and flip to the decode phase.  Returns (lanes, C)."""
+        token and flip to the decode phase.  Returns (lanes, C).
+
+        At C = 1 on a folding scheduler it dispatches nothing: the lanes'
+        next prompt tokens ride the step's decode dispatch instead
+        (:meth:`_decode_step` with ``fold``), which does the same
+        arithmetic a chunk of one does."""
         pool = self.pool
         # Under overcommit the headroom pass may preempt lanes — including
         # prefilling ones, which changes the lane set and the chunk-size
@@ -1366,6 +1403,8 @@ class ContinuousScheduler:
                 for i in lanes
             }
             C = self._pick_chunk(max(remaining.values()), pool.n_decoding)
+            if C == 1 and self._folds:
+                return len(lanes), C
             if not self.policy.paged:
                 break
             demand = {
@@ -1405,30 +1444,37 @@ class ContinuousScheduler:
                 sampled_host = np.asarray(sampled)
         self.prefill_chunks += 1
         self._c_chunks.inc()
+        self._c_ptok["chunk"].inc(int(nval.sum()))
         rec = self.obs.recorder
         for i in lanes:
             s = pool.slots[i]
-            tr = rec.get(s.uid)
-            tr.event(obs_trace.PREFILL_CHUNK, size=int(nval[i]))
+            rec.event(s.uid, obs_trace.PREFILL_CHUNK, size=int(nval[i]))
             s.filled += int(nval[i])
             if s.filled == len(s.prompt):
-                if tr.find(obs_trace.FIRST_TOKEN) is None:
-                    # A lane resumed after a decode-phase preemption
-                    # already emitted its first token in its first life —
-                    # recording (and observing) TTFT again would double
-                    # count the request.
-                    tr.event(obs_trace.FIRST_TOKEN)
-                    ttft_ms = tr.ttft_ms()
-                    self._h_ttft.observe(ttft_ms)
-                    self._h_tier_ttft.labels(tier=s.tier).observe(ttft_ms)
-                else:
-                    ttft_ms = tr.ttft_ms()
-                pool.start_decode(i, int(sampled_host[i]), ttft_ms)
-                if self._tiered:
-                    # The first token comes off the full-precision
-                    # prefill chunk, whatever the lane's tier.
-                    s.plane_log = [self._n_bits]
+                self._start_decode(i, int(sampled_host[i]))
         return len(lanes), C
+
+    def _start_decode(self, i: int, first: int) -> None:
+        """Lane ``i`` has written its last prompt row and ``first`` was
+        sampled from it: record the first token and flip the lane to the
+        decode phase."""
+        s = self.pool.slots[i]
+        tr = self.obs.recorder.get(s.uid)
+        if tr.find(obs_trace.FIRST_TOKEN) is None:
+            # A lane resumed after a decode-phase preemption already
+            # emitted its first token in its first life — recording (and
+            # observing) TTFT again would double count the request.
+            tr.event(obs_trace.FIRST_TOKEN)
+            ttft_ms = tr.ttft_ms()
+            self._h_ttft.observe(ttft_ms)
+            self._h_tier_ttft.labels(tier=s.tier).observe(ttft_ms)
+        else:
+            ttft_ms = tr.ttft_ms()
+        self.pool.start_decode(i, first, ttft_ms)
+        if self._tiered:
+            # The first token comes off the full-precision prefill chunk,
+            # whatever the lane's tier.
+            s.plane_log = [self._n_bits]
 
     # -- speculative decoding ----------------------------------------------
     def _spec_round(self, queue: Deque[_Pending], now: int) -> int:
@@ -1720,12 +1766,13 @@ class ContinuousScheduler:
                     # Evict lanes whose request finished at admission
                     # (legacy max_new == 1).
                     yield from self._retire()
-                    worked = False
-                    n_pre = chunk = n_dec = 0
+                    worked = fold = False
+                    n_pre = chunk = n_dec = n_fused = 0
                     if self.policy.chunked_prefill and pool.prefilling():
                         with self._span("prefill"):
                             n_pre, chunk = self._prefill_step(queue, now)
                         worked = True
+                        fold = self._folds and chunk == 1
                         # chunked max_new == 1: finished at first token
                         yield from self._retire()
                     if self.policy.spec_decode and pool.n_decoding:
@@ -1738,14 +1785,16 @@ class ContinuousScheduler:
                         with self._span("decode"):
                             n_dec = self._spec_round(queue, now)
                         yield from self._retire()
-                    elif pool.n_decoding:
+                    elif pool.n_decoding or fold:
                         worked = True
                         with self._span("decode"):
-                            decoded = self._decode_step(queue, now)
+                            decoded = self._decode_step(queue, now, fold)
                         if decoded is not None:
                             n_dec = int(decoded[1].sum())
+                            n_fused = len(decoded[3])
                             yield from self._retire(decoded)
-                    step.annotate(step=now, prefilling=n_pre, chunk=chunk, decoding=n_dec)
+                    step.annotate(step=now, prefilling=n_pre, chunk=chunk,
+                                  decoding=n_dec, fused=n_fused)
                     if not worked and incoming and not queue:
                         # idle gap before the next arrival: fast-forward
                         # the clock.  Only when the queue is empty — a
@@ -1786,36 +1835,54 @@ class ContinuousScheduler:
     def _span(self, phase: str):
         return self.obs.span(phase, self._record[phase])
 
-    def _decode_step(self, queue: Deque[_Pending], now: int):
+    def _decode_step(self, queue: Deque[_Pending], now: int,
+                     fold: bool = False):
         """One pooled decode step over every decode-phase lane: block
         growth, dispatch (one per plane group on tiered engines),
         sampling and the step's one host sync.  Returns the sampled
-        tokens, the lane mask and each lane's plane count (tiered), or
-        None when the headroom pass preempted every decode lane."""
+        tokens, the decode-lane mask, each lane's plane count (tiered)
+        and the folded prefill lanes, or None when the headroom pass
+        preempted every lane of the step.
+
+        ``fold`` (a chunk-1 step, see :meth:`_prefill_step`): every
+        prefilling lane joins the dispatch as a decode lane would, fed
+        its prompt token ``prompt[filled]`` at position ``filled`` —
+        the decode program writes that row's K/V (or advances the
+        recurrent state) and attends over rows ``0..filled``, exactly
+        a chunk of one.  Its sampled token is dropped, except after the
+        last prompt token, where it is the first token.  The fold is
+        chosen from the step's chunk size alone; it changes no program,
+        only the step's ``tok``/``pos``/``act`` operands.  A prefilling
+        lane's position lives in ``filled`` on the host: each fold
+        passes it, and :meth:`SlotPool.start_decode` sets the pool's."""
         pool = self.pool
         if self.policy.paged:
-            # decode growth: lanes crossing a block boundary need their
-            # next block granted before the write (one batched table
-            # update for the whole step).  Under overcommit the headroom
-            # pass may first preempt victims — possibly every decode lane
-            # — so the dispatch below re-checks n_decoding.
+            # growth: lanes crossing a block boundary need their next
+            # block granted before the write (one batched table update
+            # for the whole step, folded prefill rows included).  Under
+            # overcommit the headroom pass may first preempt victims —
+            # possibly every lane of the step — so the lane sets below
+            # are read after it.
+            demand = {i: len(s.prompt) + len(s.tokens)
+                      for i, s in enumerate(pool.slots)
+                      if s.uid is not None and s.phase == "decode"}
+            if fold:
+                demand.update((i, pool.slots[i].filled + 1)
+                              for i in pool.prefilling())
             with self._span("blocks"):
-                pool.grow_many(self._ensure_headroom({
-                    i: len(s.prompt) + len(s.tokens)
-                    for i, s in enumerate(pool.slots)
-                    if s.uid is not None and s.phase == "decode"
-                }, queue, now))
-            # blocks this step's attention actually reads: the decode
-            # lanes' live blocks (== the paged kernel's per-step HBM
-            # traffic; the gather path reads blocks_per_lane per live
-            # lane regardless)
+                pool.grow_many(self._ensure_headroom(demand, queue, now))
+            # blocks this step's attention actually reads: the live
+            # blocks of its decode and folded lanes (== the paged
+            # kernel's per-step HBM traffic; the gather path reads
+            # blocks_per_lane per live lane regardless)
             self._h_attn.observe(sum(
                 len(s.blocks) for s in pool.slots
-                if s.uid is not None and s.phase == "decode"
+                if s.uid is not None and (fold or s.phase == "decode")
             ))
-        if not pool.n_decoding:
+        fused = pool.prefilling() if fold else []
+        if not pool.n_decoding and not fused:
             return None
-        active = pool.decode_mask  # lanes live during this decode step
+        active = pool.decode_mask  # decode-phase lanes of this step
         lane_planes: Dict[int, int] = {}
         if self._tiered:
             # Group the step's decode lanes by effective plane count and
@@ -1856,15 +1923,23 @@ class ContinuousScheduler:
                 for i in groups[k]:
                     lane_planes[i] = k
         else:
+            tok, pos, act = pool.tok, pool.pos, pool.act
+            if fused:
+                ctrl = np.zeros((3, pool.n_slots), np.int32)
+                for i in fused:
+                    s = pool.slots[i]
+                    ctrl[:, i] = (1, s.prompt[s.filled], s.filled)
+                tok, pos, act = self._fold_inputs(
+                    tok, pos, act, self._place_ctrl("tok", ctrl))
             logits, pool.cache = self._decode(
-                self.engine.params, pool.cache, pool.tok, pool.pos, pool.act,
+                self.engine.params, pool.cache, tok, pos, act,
                 pool.block_table,
             )
             sampled = self.engine._sample(logits, pool.temps, pool.any_hot)
             with self._span("sync"):  # one host sync per step (streaming)
                 sampled_host = np.asarray(sampled)
             pool.tok = pool._pin("tok", sampled[:, None])
-        return sampled_host, active, lane_planes
+        return sampled_host, active, lane_planes, fused
 
     def _retire(self, decoded=None):
         """The step's ``finish`` phase: after a decode step (``decoded``
@@ -1884,13 +1959,18 @@ class ContinuousScheduler:
             yield res
 
     def _record_decode(self, sampled_host: np.ndarray, active: np.ndarray,
-                       lane_planes: Dict[int, int]) -> None:
+                       lane_planes: Dict[int, int], fused: List[int]) -> None:
         pool = self.pool
         rec = self.obs.recorder
         self.decode_steps += 1
         self._c_steps.inc()
+        # Only decode-phase lanes take a token; a folded lane's sampled
+        # token is its first token or nothing.
         pool.advance(sampled_host, active)
-        self._h_occ.observe(int(active.sum()))
+        if active.any():
+            # decode lanes only: a dispatch of folded prefill lanes alone
+            # decodes nothing
+            self._h_occ.observe(int(active.sum()))
         for i, s in enumerate(pool.slots):
             if active[i] and s.uid is not None:
                 if self._tiered:
@@ -1899,6 +1979,13 @@ class ContinuousScheduler:
                               planes=lane_planes[i])
                 else:
                     rec.event(s.uid, obs_trace.DECODE_STEP)
+        for i in fused:
+            s = pool.slots[i]
+            rec.event(s.uid, obs_trace.PREFILL_CHUNK, size=1)
+            s.filled += 1
+            if s.filled == len(s.prompt):
+                self._start_decode(i, int(sampled_host[i]))
+        self._c_ptok["decode"].inc(len(fused))
         if self.policy.paged:
             used = pool.allocator.used_count
             live = pool.live_rows()

@@ -15,6 +15,7 @@ import pytest
 
 from repro.configs import reduced_config
 from repro.models import init_params
+from repro.obs import trace as obs_trace
 from repro.serve import Request, SchedulerPolicy, ServeEngine
 
 
@@ -39,6 +40,25 @@ def _reference(params, cfg, reqs, max_len=64):
             ServeEngine(params, cfg, max_len=max_len).generate(reqs)}
 
 
+def _chunk_sizes_by_request(eng):
+    """Each request's prefill chunks, in order, from the flight recorder."""
+    return {tr.uid: [e.attrs["size"] for e in tr.events
+                     if e.kind == obs_trace.PREFILL_CHUNK]
+            for tr in eng.obs.recorder.traces()}
+
+
+def _prefill_tokens(eng, path):
+    return int(eng.obs.registry.counter(
+        "serve_prefill_tokens_total", labels=("path",)).labels(path=path).value)
+
+
+def _assert_folded(eng):
+    """Chunk-1 prefill ran inside the decode dispatch: prompt tokens went
+    through the decode program, and no chunk-1 program was ever built."""
+    assert _prefill_tokens(eng, "decode") > 0
+    assert 1 not in eng.scheduler._chunk_cache
+
+
 def test_chunked_mixed_lengths_staggered_token_identical(granite):
     cfg, params = granite
     reqs = _mixed_requests(cfg)
@@ -50,6 +70,7 @@ def test_chunked_mixed_lengths_staggered_token_identical(granite):
     for r in out:
         np.testing.assert_array_equal(ref[r.uid], r.tokens)
     assert eng.scheduler.compiled_decode_programs() == 1
+    _assert_folded(eng)
 
 
 def test_multi_chunk_prompts_and_lane_reuse(granite):
@@ -66,8 +87,12 @@ def test_multi_chunk_prompts_and_lane_reuse(granite):
     assert len(out) == len(reqs)
     for r in out:
         np.testing.assert_array_equal(ref[r.uid], r.tokens)
-    # chunk 4 over prompts up to 10 tokens => several multi-chunk prefills
-    assert eng.scheduler.prefill_chunks > len(reqs)
+    # chunk 4 over prompts up to 10 tokens => several multi-chunk prefills:
+    # chunk-4 programs, then chunk-1 steps folded into the decode dispatch
+    chunks = _chunk_sizes_by_request(eng)
+    assert sum(len(c) for c in chunks.values()) > len(reqs)
+    assert any(4 in c and 1 in c for c in chunks.values())
+    _assert_folded(eng)
 
 
 @pytest.mark.parametrize("arch", ["gemma3-12b", "recurrentgemma-9b", "mamba2-130m"])
@@ -95,6 +120,10 @@ def test_chunked_ring_and_recurrent_archs(arch):
         assert len(out) == len(reqs)
         for r in out:
             np.testing.assert_array_equal(ref[r.uid], r.tokens)
+        # chunk-1 steps ran the decode program (recurrent state carried
+        # one token at a time, ring slots written at the fill position)
+        _assert_folded(eng)
+        assert eng.scheduler.compiled_decode_programs() == 1
 
 
 def test_prefill_program_count_bounded(granite):

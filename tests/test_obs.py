@@ -457,7 +457,11 @@ def test_stream_spans_land_on_the_profiler_host_plane(granite, tmp_path):
     steps = [e for e in events if e.name == "serve.step"]
     assert len(steps) == eng.obs.registry.histogram("serve_step_ms").count
     args = [name for name, _ in steps[-1].stats]
-    assert sorted(args) == ["chunk", "decoding", "prefilling", "step"]
+    assert sorted(args) == ["chunk", "decoding", "fused", "prefilling", "step"]
+    # fused=<lanes> sums to the prompt tokens the decode program consumed
+    fused = sum(int(v) for e in steps for name, v in e.stats if name == "fused")
+    assert fused == eng.obs.registry.counter(
+        "serve_prefill_tokens_total", labels=("path",)).labels(path="decode").value > 0
 
 
 # ---------------------------------------------------------------------------

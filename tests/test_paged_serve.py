@@ -159,6 +159,18 @@ def _assert_span_accounting(engine):
             assert tr.find(obs_trace.FIRST_TOKEN) is not None, tr.uid
 
 
+def _prefill_tokens(engine, path):
+    return int(engine.obs.registry.counter(
+        "serve_prefill_tokens_total", labels=("path",)).labels(path=path).value)
+
+
+def _assert_folded(engine):
+    """Chunk-1 prefill ran inside the decode dispatch: prompt tokens went
+    through the decode program, and no chunk-1 program was ever built."""
+    assert _prefill_tokens(engine, "decode") > 0
+    assert 1 not in engine.scheduler._chunk_cache
+
+
 @pytest.mark.conformance
 @pytest.mark.parametrize("seed", range(N_SEEDS))
 def test_randomized_schedule_conformance(seed, granite, oracle, unpaged, paged,
@@ -188,6 +200,9 @@ def test_randomized_schedule_conformance(seed, granite, oracle, unpaged, paged,
         np.testing.assert_array_equal(ref[r.uid], r.tokens)
     _assert_zero_leaks(paged_kernel)
     _assert_span_accounting(paged_kernel)
+    # chunk-1 steps fold into the decode dispatch on every engine
+    for eng in (unpaged, paged, paged_kernel):
+        assert 1 not in eng.scheduler._chunk_cache
 
     if seed % 5 == 0:
         # mid-stream abandon (client disconnect, lanes possibly
@@ -203,6 +218,16 @@ def test_randomized_schedule_conformance(seed, granite, oracle, unpaged, paged,
         _assert_span_accounting(paged)
         kinds = {t.terminal.kind for t in paged.obs.recorder.traces()}
         assert kinds & {obs_trace.EVICTED, obs_trace.ABANDONED, obs_trace.FINISHED}
+
+
+@pytest.mark.conformance
+def test_fold_actually_engaged(unpaged, paged, paged_kernel):
+    """Meta-check on the module-scoped conformance engines: across the
+    seeded schedules prompt tokens really went through the decode
+    program (chunk-1 steps), and through chunk programs too."""
+    for eng in (unpaged, paged, paged_kernel):
+        _assert_folded(eng)
+        assert _prefill_tokens(eng, "chunk") > 0
 
 
 def _tiered(reqs):
@@ -334,29 +359,122 @@ def test_paged_ring_and_recurrent_archs(arch):
     """Ring-buffer (sliding-window) and recurrent (ssm/rglru) state is
     fixed-size per lane and bypasses paging — but it must still ride the
     same scheduler, survive lane churn, and wrap its ring past the
-    window while attention neighbours page."""
+    window while attention neighbours page.  The staggered schedule
+    refills a lane while the other decodes, so its prompt runs through
+    chunk-1 steps folded into the decode dispatch."""
     cfg = reduced_config(arch)
     params = init_params(jax.random.PRNGKey(1), cfg)
     local = "local" in [k.split("+")[0] for k in cfg.layer_pattern]
     max_new = cfg.window + 4 if local else 8
     rng = np.random.default_rng(7)
+    prompts = [rng.integers(0, cfg.vocab_size, size=int(rng.integers(2, 14))).astype(np.int32)
+               for _ in range(4)]
+    for stagger in (0, 2):
+        reqs = [Request(uid=i, tokens=p, max_new=max_new - stagger * i)
+                for i, p in enumerate(prompts)]
+        ref = {r.uid: r.tokens for r in
+               ServeEngine(params, cfg, max_len=64).generate(reqs)}
+        eng = ServeEngine(params, cfg, max_len=64, continuous=True,
+                          policy=SchedulerPolicy(n_slots=2, chunked_prefill=True,
+                                                 chunk_sizes=(8, 1), paged=True,
+                                                 block_size=8))
+        out = eng.generate(reqs, arrival_steps=[0, 1, 2, 3])
+        assert len(out) == len(reqs)
+        for r in out:
+            np.testing.assert_array_equal(ref[r.uid], r.tokens)
+        _assert_zero_leaks(eng)
+    _assert_folded(eng)
+
+
+def test_fold_preempts_mid_prefill_lane(granite):
+    """Overcommit preemption of a lane mid-prefill under the fold: a
+    throughput lane prefills one token a step inside the decode dispatch
+    beside a latency lane's decode, runs the 6-block pool dry, and is
+    preempted with no first token yet.  Its resume records TTFT once,
+    and both lanes stay oracle-identical."""
+    cfg, params = granite
+    rng = np.random.default_rng(3)
     reqs = [
-        Request(uid=i, tokens=rng.integers(0, cfg.vocab_size,
-                                           size=int(rng.integers(2, 14))).astype(np.int32),
-                max_new=max_new)
-        for i in range(4)
+        Request(uid=0, tokens=rng.integers(0, cfg.vocab_size, size=10).astype(np.int32),
+                max_new=11, tier="latency"),
+        Request(uid=1, tokens=rng.integers(0, cfg.vocab_size, size=12).astype(np.int32),
+                max_new=6),
     ]
     ref = {r.uid: r.tokens for r in
-           ServeEngine(params, cfg, max_len=64).generate(reqs)}
-    eng = ServeEngine(params, cfg, max_len=64, continuous=True,
+           ServeEngine(params, cfg, max_len=MAX_LEN).generate(reqs)}
+    eng = ServeEngine(params, cfg, max_len=MAX_LEN, continuous=True,
                       policy=SchedulerPolicy(n_slots=2, chunked_prefill=True,
                                              chunk_sizes=(8, 1), paged=True,
-                                             block_size=8))
-    out = eng.generate(reqs, arrival_steps=[0, 1, 2, 3])
+                                             block_size=BLOCK_SIZE, n_blocks=6,
+                                             overcommit=OVERCOMMIT))
+    out = eng.generate(reqs, arrival_steps=[0, 2])
     assert len(out) == len(reqs)
     for r in out:
         np.testing.assert_array_equal(ref[r.uid], r.tokens)
+    tr = {t.uid: t for t in eng.obs.recorder.traces()}
+    kinds = [e.kind for e in tr[1].events]
+    cut = kinds.index(obs_trace.PREEMPTED)
+    assert tr[1].events[cut].attrs["phase"] == "prefill"
+    before = [e.attrs["size"] for e in tr[1].events[:cut]
+              if e.kind == obs_trace.PREFILL_CHUNK]
+    assert before and set(before) == {1}  # folded steps only
+    for t in tr.values():
+        assert [e.kind for e in t.events].count(obs_trace.FIRST_TOKEN) == 1
+    assert eng.obs.registry.histogram("serve_ttft_ms").count == len(reqs)
+    _assert_folded(eng)
     _assert_zero_leaks(eng)
+    _assert_span_accounting(eng)
+    _assert_preemption_lifecycle(eng)
+
+
+def _fold_engine(granite, chunk_sizes, n_slots=N_SLOTS):
+    cfg, params = granite
+    return ServeEngine(params, cfg, max_len=MAX_LEN, continuous=True,
+                       policy=SchedulerPolicy(n_slots=n_slots, chunked_prefill=True,
+                                              chunk_sizes=chunk_sizes, paged=True,
+                                              block_size=BLOCK_SIZE))
+
+
+@pytest.mark.parametrize("chunk_sizes", [(1,), (8, 1)])
+def test_fold_counters_keep_their_meaning(granite, chunk_sizes):
+    """Prompt tokens are counted once, by the program that consumed them;
+    every folded token leaves one size-1 prefill_chunk event; and
+    serve_occupancy counts decode-phase lanes only (its sum is the
+    decode_step events, none of them a folded lane's)."""
+    cfg, _ = granite
+    reqs, arrivals = _random_schedule(np.random.default_rng(11), cfg, n_req=6)
+    eng = _fold_engine(granite, chunk_sizes)
+    out = eng.generate(reqs, arrival_steps=arrivals)
+    assert len(out) == len(reqs)
+    prompt = sum(len(r.tokens) for r in reqs)
+    dec, chk = _prefill_tokens(eng, "decode"), _prefill_tokens(eng, "chunk")
+    assert dec + chk == prompt and dec > 0
+    events = [e for tr in eng.obs.recorder.traces() for e in tr.events]
+    sizes = [e.attrs["size"] for e in events if e.kind == obs_trace.PREFILL_CHUNK]
+    assert sum(sizes) == prompt
+    assert sizes.count(1) >= dec
+    if chunk_sizes == (1,):
+        # every prompt token went through the decode program
+        assert chk == 0 and eng.scheduler.prefill_chunks == 0
+        assert sizes == [1] * dec
+    occ = eng.obs.registry.histogram("serve_occupancy")
+    assert occ.sum == sum(e.kind == obs_trace.DECODE_STEP for e in events)
+    assert occ.count <= eng.scheduler.decode_steps
+
+
+def test_fold_attn_read_blocks_count_folded_lanes(granite):
+    """serve_attn_read_blocks counts the blocks the kernel reads, folded
+    lanes' included: one 10-token prompt with max_new=1 is ten folded
+    steps reading ceil(rows / 4) blocks each, and no decode lane."""
+    cfg, _ = granite
+    eng = _fold_engine(granite, (1,), n_slots=2)
+    prompt = np.arange(10, dtype=np.int32) % cfg.vocab_size
+    [res] = eng.generate([Request(uid=0, tokens=prompt, max_new=1)])
+    assert len(res.tokens) == 1
+    attn = eng.obs.registry.histogram("serve_attn_read_blocks")
+    assert attn.count == eng.scheduler.decode_steps == 10
+    assert attn.sum == sum(-(-rows // BLOCK_SIZE) for rows in range(1, 11))
+    assert eng.obs.registry.histogram("serve_occupancy").count == 0
 
 
 def test_admission_blocked_then_unblocked_fifo(granite):
