@@ -55,7 +55,7 @@ def main(argv=None) -> int:
     from jax.sharding import SingleDeviceSharding
 
     from bench import traffic
-    from repro.configs import get_config
+    from bench.run import program_config
     from repro.kernels import ops
     from repro.models import init_packed_params, transformer
     from repro.serve.scheduler import SchedulerPolicy
@@ -73,7 +73,7 @@ def main(argv=None) -> int:
         entry = {c["name"]: c for c in bench["configs"]}[cell["config"]]
         config = json.loads((ROOT / entry["file"]).read_text())
         mix = json.loads((ROOT / "bench" / "traffic" / f"{cell['traffic']}.json").read_text())
-        cfg = get_config(config["program"]["arch"]).scaled(**config["program"]["overrides"])
+        cfg = program_config(config)
         n_slots, max_len, block = mix["clients"], traffic.max_len(mix), 32
         n_blocks = n_slots * math.ceil(max_len / block)
         params = place(jax.eval_shape(lambda: init_packed_params(

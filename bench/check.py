@@ -35,6 +35,11 @@ def sample(finished: Dict[int, np.ndarray], seed: int) -> List[int]:
 
 
 def reference(config: dict):
+    """The module ``bench/references/<reference>.py`` that the
+    configuration names: the harness's one source of what depends on the
+    architecture (``logits``, ``dims_of``, ``weight_std``,
+    ``FLOAT_LEAVES``, ``program_differs``, ``model_flops``; see
+    ``bench/references/dense.py``)."""
     return importlib.import_module(f"bench.references.{config['reference']}")
 
 

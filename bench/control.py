@@ -60,7 +60,6 @@ def control_readings(config, mix, dev, seconds, seeds, control_seeds, log=print)
     """Per seed: the program's widest served-token gap and, for the first
     ``control_seeds`` seeds, the control's."""
     from bench import check, traffic, weights
-    from bench.references.dense import dims_of
     from repro.core.packing import PackedWeight
 
     watch = run.WindowWatch()
@@ -72,7 +71,7 @@ def control_readings(config, mix, dev, seconds, seeds, control_seeds, log=print)
         if i:
             engine.params = None
             engine.params = weights.program_params(
-                seed, template, dims_of(config["model"]), config["n_bits"],
+                seed, template, check.reference(config), config["model"], config["n_bits"],
                 engine.cfg.pattern_len, PackedWeight)
         got = run.serve_window(engine, mix, seed, seconds, config["model"]["vocab"], watch)
         read = check.served_gaps(config, seed, got["prompts"], got["finished"],

@@ -1,4 +1,4 @@
-"""Operations and bytes of each kernel call and of the model, from shapes.
+"""Operations and bytes of each kernel call, from shapes.
 
 Counts are what the algorithm needs, not what a kernel happens to do:
 a packed weight's bytes are counted once per call however many row
@@ -34,13 +34,3 @@ def least_time(flops: float, nbytes: float, peak: dict) -> Tuple[float, str]:
     t_m = nbytes / peak["hbm_bytes_per_s"]
     return (t_c, "compute") if t_c >= t_m else (t_m, "memory")
 
-
-def model_flops(model: dict, dims: dict, positions) -> float:
-    """Forward FLOPs for tokens at the given positions (0-based): two per
-    weight of every matmul (the head over the published vocabulary) plus
-    attention's q.K and p.V over the ``p + 1`` positions each token
-    sees, in every layer."""
-    weights = (dims["n_layers"] * sum(k * n for k, n in (dims[m] for m in dims["matrices"]))
-               + model["d_model"] * model["vocab"])
-    attn = 4.0 * model["n_layers"] * model["n_heads"] * model["head_dim"]
-    return sum(2.0 * weights + attn * (p + 1) for p in positions)

@@ -173,8 +173,3 @@ def reduce_events(device: dict, host: list) -> dict:
                     for (p, k), v in kernels.items()],
     }
 
-
-def reduce(trace_dir: str) -> dict:
-    """Reduce the one trace in ``trace_dir``."""
-    device, host = split(load(trace_dir))
-    return reduce_events(device, host)

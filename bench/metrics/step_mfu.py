@@ -4,8 +4,8 @@ window's length times the chip's bf16 peak.
 A prefill chunk event of ``n`` rows counts the prompt positions it
 filled; a decode step counts the position of the token it fed.  Each
 position costs two FLOPs per matmul weight (the head included) plus
-attention over the positions it sees (``bench.costs.model_flops``)."""
-from bench import costs
+attention over the positions it sees, in the layers that attend (the
+configuration's reference module's ``model_flops``)."""
 
 
 def positions(events, prompt_len, t_open, t_close):
@@ -30,5 +30,5 @@ def read(ctx):
     pos = positions(ctx["events"], ctx["prompt_len"], ctx["t_open"], ctx["t_close"])
     if not pos:
         return None
-    flops = costs.model_flops(ctx["model"], ctx["dims"], pos)
+    flops = ctx["reference"].model_flops(ctx["model"], ctx["dims"], pos)
     return 100.0 * flops / ((ctx["t_close"] - ctx["t_open"]) * ctx["peak"]["bf16_flops"])

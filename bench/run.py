@@ -89,13 +89,18 @@ def load_reader(metric: str):
 
 def counters(engine) -> dict:
     """Program counters read at each end of the window."""
+    from bench import hostspans
+
     reg = engine.obs.registry
     occ = reg.histogram("serve_occupancy")
     attn = reg.histogram("serve_attn_read_blocks")
+    ptok = reg.counter("serve_prefill_tokens_total", labels=("path",))
     sched = engine.scheduler
     return {"t": time.perf_counter(), "occ_count": occ.count, "occ_sum": occ.sum,
             "attn_blocks": attn.sum,
-            "decode_steps": sched.decode_steps, "prefill_chunks": sched.prefill_chunks}
+            "decode_steps": sched.decode_steps, "prefill_chunks": sched.prefill_chunks,
+            "prefill_tokens": {p: ptok.labels(path=p).value for p in ("decode", "chunk")},
+            **hostspans.counters(reg)}
 
 
 def process_age() -> float:
@@ -259,8 +264,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: int, config: dict, mix
              e2e_specs: list, layer_specs: list, readers: dict, dev, chips: int, peak: dict):
     """Set-up, window, metrics and comparison of one run; returns the
     result object and the check lines that end standard error."""
-    from bench import check, devtrace, measure, traffic
-    from bench.references.dense import dims_of
+    from bench import check, devtrace, hostspans, measure, traffic
 
     age = process_age()
     device = {"platform": dev.platform, "kind": dev.device_kind, "count": chips}
@@ -295,22 +299,28 @@ def run_cell(name: str, seed: int, seconds: float, trace: int, config: dict, mix
 
     metrics = {}
     if trace:
-        reduced = devtrace.reduce(tmp.name)
+        dev_events, host_events = devtrace.split(devtrace.load(tmp.name))
         tmp.cleanup()
+        reduced = devtrace.reduce_events(dev_events, host_events)
+        spans = hostspans.reduce(dev_events, host_events)
+        del dev_events, host_events
         device["busy_s"] = reduced["busy_s"]
         device["window_s"] = reduced["window_s"]
-        ctx = {"config": config, "model": config["model"], "dims": dims_of(config["model"]),
+        ref = check.reference(config)
+        ctx = {"config": config, "model": config["model"], "reference": ref,
+               "dims": ref.dims_of(config["model"]),
                "n_bits": config["n_bits"], "n_slots": mix["clients"], "peak": peak,
                "block_size": engine.scheduler.pool.block_size, "init_weights_s": t_weights,
                "at_open": window.at_open, "at_close": window.at_close,
                "t_open": t_open, "t_close": t_close, "events": events,
-               "prompt_len": {u: len(p) for u, p in run["prompts"].items()}, "trace": reduced}
+               "prompt_len": {u: len(p) for u, p in run["prompts"].items()}, "trace": reduced,
+               "host_spans": spans}
         for m in layer_specs:
             v = readers[m["name"]](ctx)
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
         result["breakdown"] = reduced["breakdown"]
-        for line in reduced["notes"]:
+        for line in reduced["notes"] + spans["notes"]:
             log(line)
     else:
         for m in e2e_specs:
@@ -342,34 +352,37 @@ def run_cell(name: str, seed: int, seconds: float, trace: int, config: dict, mix
     return result, lines
 
 
+def program_config(config: dict):
+    """The program's ``ModelConfig``: the registry's ``program.arch`` with
+    ``program.overrides`` (a JSON list, such as a layer pattern, becomes
+    the tuple the program hashes)."""
+    from repro.configs import get_config
+
+    prog = config["program"]
+    return get_config(prog["arch"]).scaled(
+        **{k: tuple(v) if isinstance(v, list) else v for k, v in prog["overrides"].items()})
+
+
 def build(config: dict, mix: dict, seed: int, dev):
     """Weights, engine and warm-up: everything before the window's stream."""
     import jax
 
-    from bench import serve, traffic, weights
-    from bench.references.dense import dims_of
-    from repro.configs import get_config
+    from bench import check, serve, traffic, weights
     from repro.core.packing import PackedWeight
     from repro.models import init_packed_params
     from repro.obs import Observability
     from repro.serve import ServeEngine
 
-    prog = config["program"]
-    cfg = get_config(prog["arch"]).scaled(**prog["overrides"])
-    model = config["model"]
-    want = {"d_model": cfg.d_model, "n_heads": cfg.n_heads, "n_kv_heads": cfg.n_kv_heads,
-            "head_dim": cfg.resolved_head_dim, "d_ff": cfg.d_ff, "n_layers": cfg.n_layers,
-            "vocab": cfg.vocab_size, "vocab_rows": cfg.padded_vocab,
-            "tied_embedding": cfg.tie_embeddings, "rope_theta": cfg.rope_theta,
-            "norm_eps": cfg.norm_eps, "mlp": cfg.mlp_type}
-    differ = {k: (model[k], v) for k, v in want.items() if model[k] != v}
+    cfg = program_config(config)
+    ref = check.reference(config)
+    differ = ref.program_differs(config["model"], cfg)
     if differ:
         raise BenchError(f"configuration and program disagree (file, program): {differ}")
     n_bits = config["n_bits"]
     template = jax.eval_shape(lambda: init_packed_params(jax.random.PRNGKey(0), cfg, n_bits))
     t0 = time.perf_counter()
-    params = weights.program_params(seed, template, dims_of(model), n_bits, cfg.pattern_len,
-                                    PackedWeight)
+    params = weights.program_params(seed, template, ref, config["model"], n_bits,
+                                    cfg.pattern_len, PackedWeight)
     jax.block_until_ready(params)
     t_weights = time.perf_counter() - t0
     max_len = traffic.max_len(mix)

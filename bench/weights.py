@@ -3,16 +3,18 @@
 Every packed matrix is drawn directly in its served form: uniform random
 bit-plane and sign bytes (so each magnitude code is uniform on
 ``0 .. 2^n - 1`` and each sign a fair coin) and one scale per layer.
-Float leaves (embedding, norm gains) are Gaussian.  Each layer's leaves
+The embedding and the norm gains are Gaussian.  Each layer's leaves
 come from a key folded from the seed, the leaf's name and the layer
-index, so the reference regenerates any single layer on its own
-(``layer_leaves``) and never reads an array the program holds.
+index, so the reference regenerates any single layer on its own and
+never reads an array the program holds.
 
-Scales keep a 40-layer residual stream well conditioned (the stream's
-growth is shared out over the depth), and the final norm's gain is
-drawn around zero so that tied logits do not simply echo the input
-token.  Both choices only fix what random weights look like; the
-kernels do the same work for any bytes.
+What depends on the architecture is the reference module's
+(``bench.check.reference``): which roles a layer of each kind holds,
+each packed matrix's shape and weight scale, and how each other float
+leaf is drawn.  The final norm's gain is drawn around zero so that tied
+logits do not simply echo the input token; like the scales, that only
+fixes what random weights look like, and the kernels do the same work
+for any bytes.
 """
 from __future__ import annotations
 
@@ -21,17 +23,6 @@ import zlib
 
 import jax
 import jax.numpy as jnp
-
-# Residual branches together add this many times the embedding's
-# variance to the stream.
-STREAM_GROWTH = 4.0
-# Score standard deviation of a query-key product (sharp enough that
-# attention output depends on a few positions, not a flat mean).
-SCORE_STD = 3.0
-# rms of attention output and of the MLP's gated activation, for unit
-# inputs (measured on random draws; only used to size output scales).
-ATTN_OUT_RMS = 0.5
-MLP_ACT_RMS = 0.6
 
 def seed_words(seed: int):
     """A seed of up to 64 bits as two uint32 words, passed to the jitted
@@ -54,19 +45,6 @@ def _bytes(key, shape):
     return jax.lax.bitcast_convert_type(words, jnp.uint8).reshape(shape)
 
 
-def weight_std(name: str, k: int, n_layers: int) -> float:
-    """Standard deviation of the dequantised weight of matrix ``name``
-    with reduction size ``k``."""
-    branch = math.sqrt(STREAM_GROWTH / (2 * n_layers))
-    if name in ("wq", "wk"):
-        return math.sqrt(SCORE_STD) / math.sqrt(k)
-    if name == "wo":
-        return branch / (ATTN_OUT_RMS * math.sqrt(k))
-    if name == "w_down":
-        return branch / (MLP_ACT_RMS * math.sqrt(k))
-    return 1.0 / math.sqrt(k)
-
-
 def code_scale(std: float, n_bits: int) -> float:
     """Scale ``s`` such that ``sign * q * s / (2^n - 1)``, with ``q``
     uniform on ``0 .. 2^n - 1``, has standard deviation ``std``."""
@@ -74,14 +52,14 @@ def code_scale(std: float, n_bits: int) -> float:
     return std / math.sqrt((2 * lv + 1) / (6 * lv))
 
 
-def packed_leaf(seed, name: str, layer: int, k: int, n: int, n_bits: int,
-                n_layers: int):
-    """(planes (n_bits, k/8, n), sign (k/8, n), scale ()) of one matrix."""
+def packed_leaf(seed, name: str, layer: int, k: int, n: int, n_bits: int, std: float):
+    """(planes (n_bits, k/8, n), sign (k/8, n), scale ()) of one matrix
+    whose dequantised weight has standard deviation ``std``."""
     key = _key(seed, name, layer)
     kp, ks = jax.random.split(key)
     planes = _bytes(kp, (n_bits, k // 8, n))
     sign = _bytes(ks, (k // 8, n))
-    scale = jnp.float32(code_scale(weight_std(name, k, n_layers), n_bits))
+    scale = jnp.float32(code_scale(std, n_bits))
     return planes, sign, scale
 
 
@@ -97,37 +75,36 @@ def embedding(seed, vocab_rows: int, d: int):
     return z / math.sqrt(d)
 
 
-def layer_leaves(seed, layer: int, dims: dict, n_bits: int):
-    """Every leaf of decoder layer ``layer``, by its published role:
-    ``{name: (planes, sign, scale)}`` for the matrices and
-    ``{name: gain}`` for the norms.  ``dims`` maps a matrix name to its
-    (K, N)."""
-    L = dims["n_layers"]
-    out = {m: packed_leaf(seed, m, layer, *dims[m], n_bits, L)
-           for m in dims["matrices"]}
-    out["norm1"] = norm_gain(seed, "norm1", layer, dims["d_model"])
-    out["norm2"] = norm_gain(seed, "norm2", layer, dims["d_model"])
-    return out
-
-
 def _path_names(path):
     return [str(getattr(q, "key", getattr(q, "name", getattr(q, "idx", q)))) for q in path]
 
 
-def program_params(seed: int, template, dims: dict, n_bits: int, layers_per_block: int,
+def program_params(seed: int, template, ref, model: dict, n_bits: int, layers_per_block: int,
                    packed_type):
     """The program's parameter tree, filled from ``seed`` in one jitted call.
 
     ``template`` is the program's own tree of shapes (``jax.eval_shape``
     of its packed init); only its structure is used.  Layer ``l`` of the
     depth sits at ``blocks/p{l % P}`` slice ``l // P`` (P =
-    ``layers_per_block``).  Norm leaves hold ``gain - 1``: the program's
-    RMSNorm multiplies by ``1 + scale``.  Any leaf this function does
-    not know raises, so a change of the program's tree cannot pass
-    unseen."""
+    ``layers_per_block``).  The reference module ``ref`` says which roles
+    a layer of each kind holds (``ref.dims_of(model)``).  A packed leaf
+    of a role is drawn at the role's ``ref.weight_std``, a leaf
+    ``<role>/scale`` is the gain of an RMSNorm, and any other leaf is
+    drawn by ``ref.FLOAT_LEAVES[role]``.  Norm leaves hold ``gain - 1``:
+    the program's RMSNorm multiplies by ``1 + scale``.  Any leaf the
+    reference does not name raises, so a change of the program's tree
+    cannot pass unseen."""
     is_pw = lambda x: isinstance(x, packed_type)
     flat, treedef = jax.tree_util.tree_flatten_with_path(template, is_leaf=is_pw)
+    dims = ref.dims_of(model)
     L = dims["n_layers"]
+
+    def roles_at(pos: int, nb: int):
+        kinds = {dims["kinds"][l] for l in range(pos, nb * layers_per_block, layers_per_block)}
+        if len(kinds) != 1:
+            raise ValueError(f"layers at pattern position {pos} are of kinds {sorted(kinds)} "
+                             "in the reference: the program's pattern does not repeat them")
+        return dims["roles"][kinds.pop()]
 
     def build(seed):
         leaves = []
@@ -137,19 +114,26 @@ def program_params(seed: int, template, dims: dict, n_bits: int, layers_per_bloc
                 pos = int(names[1][1:])
                 nb = (leaf.planes if is_pw(leaf) else leaf).shape[0]
                 layers = jnp.arange(nb) * layers_per_block + pos
-                role = names[-1]
-                if is_pw(leaf):
+                roles, role = roles_at(pos, nb), names[-1]
+                if is_pw(leaf) and role in roles:
                     k, n = dims[role]
+                    std = ref.weight_std(role, k, L)
                     planes, sign, scale = jax.vmap(
-                        lambda l, r=role, k=k, n=n: packed_leaf(seed, r, l, k, n, n_bits, L)
-                    )(layers)
+                        lambda l, r=role, k=k, n=n, std=std:
+                        packed_leaf(seed, r, l, k, n, n_bits, std))(layers)
                     leaves.append(packed_type(planes=planes, sign=sign,
                                               scale=scale.reshape(nb, 1, 1),
                                               n_bits=leaf.n_bits, k=leaf.k))
                     continue
-                if names[-1] == "scale" and names[-2] in ("norm1", "norm2"):
-                    g = jax.vmap(lambda l, r=names[-2]: norm_gain(seed, r, l, dims["d_model"]))(layers)
+                if role == "scale" and names[-2] in roles:
+                    g = jax.vmap(lambda l, r=names[-2], d=leaf.shape[-1]:
+                                 norm_gain(seed, r, l, d))(layers)
                     leaves.append(g - 1.0)
+                    continue
+                if not is_pw(leaf) and role in roles and role in ref.FLOAT_LEAVES:
+                    x = jax.vmap(lambda l, r=role, shape=leaf.shape[1:]:
+                                 ref.FLOAT_LEAVES[r](_key(seed, r, l), shape))(layers)
+                    leaves.append(x.astype(leaf.dtype))
                     continue
             elif names == ["embed"]:
                 leaves.append(embedding(seed, *leaf.shape))
@@ -159,7 +143,8 @@ def program_params(seed: int, template, dims: dict, n_bits: int, layers_per_bloc
                 continue
             elif names == ["lm_head"] and is_pw(leaf):
                 k, n = dims["lm_head"]
-                planes, sign, scale = packed_leaf(seed, "lm_head", -1, k, n, n_bits, L)
+                planes, sign, scale = packed_leaf(seed, "lm_head", -1, k, n, n_bits,
+                                                  ref.weight_std("lm_head", k, L))
                 leaves.append(packed_type(planes=planes, sign=sign, scale=scale,
                                           n_bits=leaf.n_bits, k=leaf.k))
                 continue

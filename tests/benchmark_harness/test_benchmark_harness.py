@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from bench import costs, devtrace, measure, peaks, traffic
+from bench.references import dense
 
 DATA = pathlib.Path(__file__).parent / "data"
 
@@ -228,9 +229,9 @@ def test_least_time_names_its_roof():
 def test_model_flops_counts_weights_and_attention():
     model = {"d_model": 8, "n_layers": 2, "n_heads": 2, "head_dim": 4, "vocab": 10,
              "vocab_rows": 16}
-    dims = {"matrices": ("wq",), "wq": (8, 8), "n_layers": 2}
+    dims = {"matrices": ("wq",), "wq": (8, 8), "n_layers": 2, "attn_layers": 2}
     # two layers of one 8x8 matrix and a tied 8x10 head; attention 4*2*2*4*(p+1)
-    assert costs.model_flops(model, dims, [0, 3]) == \
+    assert dense.model_flops(model, dims, [0, 3]) == \
         2 * (2 * (2 * 64 + 80)) + 64 * (1 + 4)
 
 

@@ -1,7 +1,7 @@
 """The scheduler's host spans as the benchmark reads them: the reduction
 of ``serve.*`` spans against the device's idle time, the readers of the
-three scheduler metrics, and the spans the program really writes into a
-CPU profile."""
+four scheduler metrics, the spans the program really writes into a CPU
+profile, and a traced run of the harness that reads them."""
 import importlib.util
 import pathlib
 
@@ -11,7 +11,7 @@ import pytest
 from bench import devtrace, hostspans, run
 from repro.obs import Registry
 
-from test_benchmark_control import MIX, SECONDS, TINY
+from test_benchmark_control import MIX, PEAK, SECONDS, TINY
 
 METRICS = pathlib.Path(__file__).resolve().parents[2] / "bench" / "metrics"
 
@@ -62,25 +62,30 @@ def test_a_trace_without_steps_reads_no_host_idle():
     assert r["idle_by_span"] == pytest.approx({hostspans.OUTSIDE: 4.0})
 
 
-def _ends(count, ms, sync_s, blocks_s):
+def _ends(count, ms, sync_s, blocks_s, via_decode=0, via_chunk=0):
     return {"step_count": count, "step_ms_sum": ms,
             "host_s": {p: {"sync": sync_s, "blocks": blocks_s}.get(p, 0.0)
-                       for p in hostspans.PHASES}}
+                       for p in hostspans.PHASES},
+            "prefill_tokens": {"decode": via_decode, "chunk": via_chunk}}
 
 
 def test_readers_give_their_values():
-    ctx = {"at_open": _ends(10, 1000.0, 0.5, 0.10), "at_close": _ends(20, 2600.0, 1.4, 0.13),
+    ctx = {"at_open": _ends(10, 1000.0, 0.5, 0.10, 40, 100),
+           "at_close": _ends(20, 2600.0, 1.4, 0.13, 100, 140),
            "host_spans": hostspans.reduce(DEVICE, HOST)}
     assert _reader("host_step_ms")(ctx) == pytest.approx((1600.0 - 900.0) / 10)
     assert _reader("block_table_ms")(ctx) == pytest.approx(3.0)
     assert _reader("idle_host_share")(ctx) == pytest.approx(30.0)
+    assert _reader("prefill_via_decode_share")(ctx) == pytest.approx(60.0)
 
 
-@pytest.mark.parametrize("name", ["host_step_ms", "block_table_ms", "idle_host_share"])
+@pytest.mark.parametrize("name", ["host_step_ms", "block_table_ms", "idle_host_share",
+                                  "prefill_via_decode_share"])
 def test_readers_read_none_without_steps(name):
-    """A program that records no ``serve.step`` (zero counts, no spans in
-    the trace), and a harness whose context lacks the readings."""
-    still = {"at_open": _ends(7, 70.0, 0.0, 0.0), "at_close": _ends(7, 70.0, 0.0, 0.0),
+    """A program that records no ``serve.step`` and prefills nothing (zero
+    counts, no spans in the trace), and a harness whose context lacks the
+    readings."""
+    still = {"at_open": _ends(7, 70.0, 0.0, 0.0, 5, 5), "at_close": _ends(7, 70.0, 0.0, 0.0, 5, 5),
              "host_spans": hostspans.reduce(DEVICE, WINDOW)}
     assert _reader(name)(still) is None
     bare = {"at_open": {"occ_count": 1}, "at_close": {"occ_count": 2}}
@@ -114,3 +119,33 @@ def test_the_program_writes_the_spans_the_reduction_reads(tmp_path):
     assert sum(r["idle_by_span"].values()) == pytest.approx(r["idle_s"])
     assert {"admit", "blocks", "prefill", "decode", "sync", "finish"} <= set(r["idle_by_span"])
     assert 0 < r["idle_host_s"] < r["idle_s"]
+
+
+def test_a_traced_run_reads_the_scheduler_metrics(monkeypatch):
+    """``run_cell`` with ``--trace 1`` on the CPU: the trace is split once
+    (the CPU has no device plane, so its device half is given empty) and
+    both halves feed the device reduction and the host spans; the
+    window's counters feed the four scheduler readers, and ``step_mfu``
+    reads the reference's FLOPs.  ``correct`` means what it means
+    untraced."""
+    def cpu_split(profile):
+        host = [ev for plane in profile.planes if plane.name.startswith("/host:CPU")
+                for ln in plane.lines for ev in devtrace._events(ln)]
+        return {"XLA Ops": [], "XLA Modules": []}, host
+
+    monkeypatch.setattr(devtrace, "split", cpu_split)
+    names = ["host_step_ms", "block_table_ms", "idle_host_share", "prefill_via_decode_share",
+             "decode_occupancy", "step_mfu", "decode_step_ms"]
+    specs = [{"name": n, "unit": "%"} for n in names]
+    readers = {n: run.load_reader(n) for n in names}
+    result, lines = run.run_cell("tiny", 2**31 + 17, SECONDS, 1, TINY, MIX, [], specs, readers,
+                                 jax.devices("cpu")[0], 1, PEAK)
+    assert result["correct"] is True, lines
+    got = result["metrics"]
+    # no device program ran in the trace: nothing for the device readers
+    assert "decode_step_ms" not in got
+    assert set(got) == set(names) - {"decode_step_ms"}
+    assert got["host_step_ms"]["value"] > got["block_table_ms"]["value"] > 0
+    assert 0 < got["idle_host_share"]["value"] < 100
+    assert 0 < got["prefill_via_decode_share"]["value"] <= 100
+    assert result["device"]["busy_s"] == 0 and result["device"]["window_s"] > 0
